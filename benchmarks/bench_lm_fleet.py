@@ -8,9 +8,8 @@ Two legs:
     cohort's transformer fwd+bwd epochs are one fused scan launch),
   * model-axis plane ops — the server-side kernels at the LM delta row
     width, single-device vs an R×M ``(plane, model)`` mesh with
-    ``REPRO_PLANE_MODEL_COMPUTE`` on and off. Runs in subprocesses with a
-    forced 8-device host so the CPU CI tracks the dispatch overhead and
-    TPU runs track the real speedup.
+    ``REPRO_PLANE_MODEL_COMPUTE`` on and off, over the devices this process
+    holds (on the CPU backend, a child with a forced 8-device host).
 
 ``--json`` writes BENCH_lm_fleet.json at the repo root so the perf
 trajectory is tracked across PRs.
@@ -25,7 +24,6 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
 import time
 
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -67,54 +65,61 @@ def bench_fleet_scaling(sizes: tuple[int, ...], rounds: int) -> list[dict]:
     return rows
 
 
-_CHILD = textwrap.dedent("""
-    import json, os, time
-    import jax, jax.numpy as jnp
-    from repro.kernels import ops
+def _model_axis_rows(dim: int, reps: int) -> list[dict]:
+    """Pairwise-L1 plane kernel timings at the LM delta width over this
+    process's devices: single device, then (with an even count >= 4) an R×M
+    mesh with model-axis compute on and off (storage sharded, compute
+    replicated)."""
+    import jax
 
-    R, K, D, reps = 512, 8, %(dim)d, %(reps)d
-    xs = jax.random.normal(jax.random.PRNGKey(0), (R, D))
-    cs = jax.random.normal(jax.random.PRNGKey(1), (K, D))
-    mesh = None
-    if os.environ.get("BENCH_MESH") == "1":
-        from repro.launch.mesh import make_plane_mesh
-        mesh = make_plane_mesh(len(jax.devices()) // 2, dim_shards=2)
-    ops.l1_distance_pairwise(xs, cs, mesh=mesh).block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        ops.l1_distance_pairwise(xs, cs, mesh=mesh).block_until_ready()
-    print(json.dumps({"l1_us": (time.perf_counter() - t0) / reps * 1e6}))
-""")
+    from repro.kernels import ops
+    from repro.launch.mesh import make_plane_mesh
+
+    n = len(jax.devices())
+    xs = jax.random.normal(jax.random.PRNGKey(0), (512, dim))
+    cs = jax.random.normal(jax.random.PRNGKey(1), (8, dim))
+    modes = [("single-device", None, "on")]
+    if n >= 4 and n % 2 == 0:
+        mesh = make_plane_mesh(n // 2, dim_shards=2)
+        shape = f"{n // 2}x2"
+        modes += [(f"mesh {shape} model-compute on", mesh, "on"),
+                  (f"mesh {shape} model-compute off", mesh, "off")]
+    rows = []
+    knob = os.environ.get("REPRO_PLANE_MODEL_COMPUTE")
+    try:
+        for name, mesh, compute in modes:
+            os.environ["REPRO_PLANE_MODEL_COMPUTE"] = compute
+            ops.l1_distance_pairwise(xs, cs, mesh=mesh).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                ops.l1_distance_pairwise(xs, cs, mesh=mesh).block_until_ready()
+            rows.append({"mode": name, "l1_us": (time.perf_counter() - t0) / reps * 1e6,
+                         "device_kind": jax.devices()[0].device_kind})
+    finally:
+        if knob is None:
+            os.environ.pop("REPRO_PLANE_MODEL_COMPUTE", None)
+        else:
+            os.environ["REPRO_PLANE_MODEL_COMPUTE"] = knob
+    return rows
 
 
 def bench_model_axis(dim: int, reps: int = 30) -> list[dict]:
-    """Child-process timings of the pairwise-L1 plane kernel at the LM
-    delta width: single device, R×M mesh with model-axis compute, and the
-    same mesh with compute forced off (storage sharded, compute
-    replicated)."""
-    rows = []
-    modes = [
-        ("single-device", {}, "0"),
-        ("mesh 4x2 model-compute on", {"REPRO_PLANE_MODEL_COMPUTE": "on"}, "1"),
-        ("mesh 4x2 model-compute off", {"REPRO_PLANE_MODEL_COMPUTE": "off"}, "1"),
-    ]
-    for name, extra, mesh_on in modes:
-        env = dict(os.environ)
-        env.update(extra)
-        env["BENCH_MESH"] = mesh_on
-        env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src")
-        if mesh_on == "1":
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                                + " --xla_force_host_platform_device_count=8")
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD % {"dim": dim, "reps": reps}],
-            env=env, capture_output=True, text=True, timeout=600,
-        )
-        if out.returncode != 0:
-            rows.append({"mode": name, "l1_us": None, "error": out.stderr[-300:]})
-            continue
-        rows.append({"mode": name, **json.loads(out.stdout.strip().splitlines()[-1])})
-    return rows
+    """The model-axis leg over the devices this process already holds. Only
+    on the CPU backend with too few devices for a 4x2 mesh does it run in a
+    child with a forced 8-device host (a child never needs an accelerator
+    that the parent holds). A failing leg raises."""
+    import jax
+
+    if jax.default_backend() != "cpu" or len(jax.devices()) >= 4:
+        return _model_axis_rows(dim, reps)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join((os.path.join(REPO_ROOT, "src"), REPO_ROOT))
+    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+    code = ("import json; from benchmarks.bench_lm_fleet import _model_axis_rows; "
+            f"print(json.dumps(_model_axis_rows({dim}, {reps})))")
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                         text=True, timeout=600, check=True)
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def run(quick: bool = False, sizes: tuple[int, ...] = (8, 16, 32), rounds: int = 2,

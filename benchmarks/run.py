@@ -28,6 +28,7 @@ from benchmarks import (
     bench_server_throughput,
     bench_slow_device_drop,
 )
+from repro.common.compile_cache import use_compile_cache
 
 BENCHES = {
     "accuracy_time": bench_accuracy_time.run,       # Tab.1 / Fig.8
@@ -55,6 +56,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="reduced sizes for smoke runs")
     ap.add_argument("--only", default=None, help="comma-separated bench names")
     args = ap.parse_args()
+    use_compile_cache()
 
     names = list(BENCHES) if not args.only else [n.strip() for n in args.only.split(",")]
     failures = []

@@ -9,11 +9,13 @@ and broadcasts fresh cluster models on demand.
 """
 import numpy as np
 
+from repro.common.compile_cache import use_compile_cache
 from repro.fl.experiment import build_clients, build_strategy
 from repro.fl.simulator import Simulator
 
 
 def main() -> None:
+    use_compile_cache()
     # 1. a federated task: 12 devices, 4 latent user groups, non-IID labels
     task, clients, init_params = build_clients(
         "har", num_clients=12, seed=0, latent_clusters=4,

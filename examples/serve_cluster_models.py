@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import use_compile_cache
 from repro.configs import ARCH_REGISTRY
 from repro.configs.base import reduced_config
 from repro.core.server import EchoPFLServer
@@ -24,6 +25,7 @@ from repro.models.steps import TrainState, make_optimizer, make_prefill_step
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = reduced_config(ARCH_REGISTRY["gemma2-2b"], d_model=64, periods=2)
     key = jax.random.PRNGKey(0)
     init = init_params(cfg, key)

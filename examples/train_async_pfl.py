@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpointer import Checkpointer, restore_pytree, save_pytree
+from repro.common.compile_cache import use_compile_cache
 from repro.configs import ARCH_REGISTRY
 from repro.configs.base import reduced_config
 from repro.core.server import EchoPFLServer
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--local-steps", type=int, default=5)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced_config(ARCH_REGISTRY["llama3.2-1b"], d_model=64, periods=2)
     key = jax.random.PRNGKey(0)

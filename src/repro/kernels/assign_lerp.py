@@ -16,57 +16,60 @@ simply discarded and a plain row lerp runs instead.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.l1_distance import l1_distance
+from repro.kernels.l1_distance import LANES, l1_distance, row_tiling, to_lanes
 
 
-def _select_lerp_kernel(idx_ref, c_ref, u_ref, o_ref, *, beta: float):
+def _select_lerp_kernel(idx_ref, c_ref, u_ref, o_ref, *, beta: float, fence):
     del idx_ref  # consumed by the index maps
-    c = c_ref[...].astype(jnp.float32)
+    c = c_ref[...].astype(jnp.float32)  # (block_r, 128) of the winning row
     u = u_ref[...].astype(jnp.float32)
     # two-op form pinned (no FMA contraction): the blend must emit the same
     # bits as plane.lerp_vec and the coalesced ingest scan, whatever fusion
     # context this kernel lowers in (see assign_and_lerp_ref's rationale)
-    m1, m2 = jax.lax.optimization_barrier(((1.0 - beta) * c, beta * u))
+    m1, m2 = fence(((1.0 - beta) * c, beta * u))
     o_ref[...] = m1 + m2
 
 
-def _select_lerp(
+def select_lerp(
     centers: jax.Array,  # (C, N)
     u: jax.Array,  # (N,)
     idx: jax.Array,  # () int32 — which center row to blend
     beta: float,
     *,
-    block_n: int,
-    interpret: bool,
+    block_n: int = 65536,
+    interpret: bool = False,
 ) -> jax.Array:
+    """``(1 - beta) * centers[idx] + beta * u`` reading only row ``idx``."""
     C, N = centers.shape
-    n_p = math.ceil(N / block_n) * block_n
-    cp = jnp.pad(centers, ((0, 0), (0, n_p - N)))
-    up = jnp.pad(u, (0, n_p - N)).reshape(1, n_p)
+    rows, block_r = row_tiling(N, block_n)
+    cp = to_lanes(centers, rows)  # (C, rows, 128)
+    up = to_lanes(u, rows)  # (rows, 128)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_p // block_n,),
+        grid=(rows // block_r,),
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda n, idx_ref: (idx_ref[0], n)),
-            pl.BlockSpec((1, block_n), lambda n, idx_ref: (0, n)),
+            pl.BlockSpec((pl.squeezed, block_r, LANES), lambda r, idx_ref: (idx_ref[0], r, 0)),
+            pl.BlockSpec((block_r, LANES), lambda r, idx_ref: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda n, idx_ref: (0, n)),
+        out_specs=pl.BlockSpec((block_r, LANES), lambda r, idx_ref: (r, 0)),
     )
+    # the interpreter runs the body through XLA, which would contract the
+    # blend into an FMA without the barrier; Mosaic has no lowering for it
+    fence = jax.lax.optimization_barrier if interpret else (lambda x: x)
     out = pl.pallas_call(
-        functools.partial(_select_lerp_kernel, beta=beta),
+        functools.partial(_select_lerp_kernel, beta=beta, fence=fence),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n_p), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
     )(jnp.reshape(idx, (1,)).astype(jnp.int32), cp, up)
-    return out[0, :N]
+    return out.reshape(-1)[:N]
 
 
 def assign_and_lerp(
@@ -79,11 +82,7 @@ def assign_and_lerp(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (dists (C,) fp32, idx () int32 argmin, blended (N,) fp32)
     where ``blended = (1 - beta) * centers[idx] + beta * u``."""
-    (N,) = u.shape
     dists = l1_distance(u, centers, block_n=block_n, interpret=interpret)
     idx = jnp.argmin(dists).astype(jnp.int32)
-    lerp_block = min(block_n, max(128, 1 << (N - 1).bit_length()))
-    blended = _select_lerp(
-        centers, u, idx, beta, block_n=lerp_block, interpret=interpret
-    )
+    blended = select_lerp(centers, u, idx, beta, block_n=block_n, interpret=interpret)
     return dists, idx, blended

@@ -9,7 +9,8 @@ that persists across the sequential innermost grid dimension (TPU grids
 execute serially per core, so scratch carries state instead of CUDA's
 shared-memory reductions).
 
-The kernel also emits the log-sum-exp rows, which the backward kernels
+The kernel also emits the log-sum-exp rows (a ``(block_q, 1)`` column per
+tile, the layout of the running max), which the backward kernels
 (flash_attention_bwd.py) consume to recompute probability tiles instead of
 storing the O(S^2) matrix — that recomputation is what keeps attention HBM
 traffic at O(S^2 * d / block) instead of O(S^2).
@@ -29,10 +30,21 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def dot_precision(dtype) -> jax.lax.Precision:
+    """Precision of the kernels' f32 dots. The MXU's default is one bf16
+    pass, which rounds f32 operands: f32 inputs take the full-f32 passes.
+    Narrower inputs keep the single pass (their q/k/v values are exact in
+    bf16; the probability tiles round, as in any bf16 flash kernel)."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, window: int | None, softcap: float | None,
     block_q: int, block_k: int, q_pos0: int, num_k_blocks: int, kv_len: int,
+    precision: jax.lax.Precision,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -47,7 +59,7 @@ def _flash_kernel(
     k = k_ref[0, 0].astype(jnp.float32)  # (block_k, hd)
     v = v_ref[0, 0].astype(jnp.float32)  # (block_k, dv)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (bq, bk)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=precision) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
 
@@ -67,7 +79,8 @@ def _flash_kernel(
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)  # (bq, bk)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), precision=precision)
     m_scr[...] = m_new
     l_scr[...] = l_new
 
@@ -75,7 +88,7 @@ def _flash_kernel(
     def _finalize():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l_safe))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l_safe)
 
 
 def _blocks(sq: int, sk: int, block_q: int, block_k: int) -> tuple[int, int]:
@@ -123,6 +136,7 @@ def flash_attention_with_lse(
         _flash_kernel,
         scale=scale, causal=causal, window=window, softcap=softcap,
         block_q=block_q, block_k=block_k, q_pos0=q_pos0, num_k_blocks=nk, kv_len=Sk,
+        precision=dot_precision(q.dtype),
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -134,11 +148,11 @@ def flash_attention_with_lse(
         ],
         out_specs=(
             pl.BlockSpec((1, 1, block_q, dv_p), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, sq_p, dv_p), q.dtype),
-            jax.ShapeDtypeStruct((B, H, sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, sq_p, 1), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),     # running max m
@@ -147,7 +161,7 @@ def flash_attention_with_lse(
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return o[:, :, :Sq, :dv], lse[:, :, :Sq]
+    return o[:, :, :Sq, :dv], lse[:, :, :Sq, 0]
 
 
 def flash_attention(

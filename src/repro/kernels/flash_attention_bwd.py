@@ -33,10 +33,11 @@ NEG_INF = -1e30
 
 
 def _tile_ds_p(q, k, lse_tile, *, scale, softcap, causal, window,
-               q_pos0, q_pos_base, k_pos_base, q_len, kv_len, block_q, block_k):
+               q_pos0, q_pos_base, k_pos_base, q_len, kv_len, block_q, block_k,
+               precision):
     """Recompute (p, s->ds chain factor, mask) for one tile. Returns
     (p, chain) where chain is d(softcap)/d(s_raw) (ones if no softcap)."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=precision) * scale
     if softcap is not None:
         t = jnp.tanh(s / softcap)
         s = softcap * t
@@ -51,14 +52,14 @@ def _tile_ds_p(q, k, lse_tile, *, scale, softcap, causal, window,
         mask &= q_pos >= k_pos
     if window is not None:
         mask &= (q_pos - k_pos) < window
-    p = jnp.where(mask, jnp.exp(s - lse_tile[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse_tile), 0.0)
     return p, chain
 
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref, dq_scr,
     *, scale, causal, window, softcap, block_q, block_k,
-    q_pos0, num_k_blocks, q_len, kv_len,
+    q_pos0, num_k_blocks, q_len, kv_len, precision,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -71,19 +72,21 @@ def _dq_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
+    lse = lse_ref[0, 0]  # (block_q, 1)
     dsum = dsum_ref[0, 0]
 
     p, chain = _tile_ds_p(
         q, k, lse, scale=scale, softcap=softcap, causal=causal, window=window,
         q_pos0=q_pos0, q_pos_base=qi * block_q, k_pos_base=ki * block_k,
         q_len=q_len, kv_len=kv_len, block_q=block_q, block_k=block_k,
+        precision=precision,
     )
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))  # (bq, bk)
-    ds = p * (dp - dsum[:, None])
+    dot = functools.partial(jax.lax.dot_general, precision=precision)
+    dp = dot(do, v, (((1,), (1,)), ((), ())))  # (bq, bk)
+    ds = p * (dp - dsum)
     if chain is not None:
         ds = ds * chain
-    dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ()))) * scale
+    dq_scr[...] += dot(ds, k, (((1,), (0,)), ((), ()))) * scale
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -93,7 +96,7 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dk_ref, dv_ref, dk_scr, dv_scr,
     *, scale, causal, window, softcap, block_q, block_k,
-    q_pos0, num_q_blocks, num_inner, q_len, kv_len,
+    q_pos0, num_q_blocks, num_inner, q_len, kv_len, precision,
 ):
     ki = pl.program_id(2)
     gi = pl.program_id(3)  # linearized (query-group g, q block qi)
@@ -108,20 +111,22 @@ def _dkv_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
+    lse = lse_ref[0, 0]  # (block_q, 1)
     dsum = dsum_ref[0, 0]
 
     p, chain = _tile_ds_p(
         q, k, lse, scale=scale, softcap=softcap, causal=causal, window=window,
         q_pos0=q_pos0, q_pos_base=qi * block_q, k_pos_base=ki * block_k,
         q_len=q_len, kv_len=kv_len, block_q=block_q, block_k=block_k,
+        precision=precision,
     )
-    dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))  # (bk, dv)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - dsum[:, None])
+    dot = functools.partial(jax.lax.dot_general, precision=precision)
+    dv_scr[...] += dot(p, do, (((0,), (0,)), ((), ())))  # (bk, dv)
+    dp = dot(do, v, (((1,), (1,)), ((), ())))
+    ds = p * (dp - dsum)
     if chain is not None:
         ds = ds * chain
-    dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ()))) * scale
+    dk_scr[...] += dot(ds, q, (((0,), (0,)), ((), ()))) * scale
 
     @pl.when(gi == num_inner - 1)
     def _finalize():
@@ -153,7 +158,7 @@ def flash_attention_bwd(
 
     hd_p = math.ceil(hd / 128) * 128
     dv_p = math.ceil(dv / 128) * 128
-    from repro.kernels.flash_attention import _blocks
+    from repro.kernels.flash_attention import _blocks, dot_precision
 
     block_q, block_k = _blocks(Sq, Sk, block_q, block_k)
     sq_p = math.ceil(Sq / block_q) * block_q
@@ -164,14 +169,14 @@ def flash_attention_bwd(
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, sk_p - Sk), (0, hd_p - hd)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, sk_p - Sk), (0, dv_p - dv)))
     dop = jnp.pad(do, ((0, 0), (0, 0), (0, sq_p - Sq), (0, dv_p - dv)))
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, sq_p - Sq)))
+    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, sq_p - Sq)))[..., None]
     # D = rowsum(do * o): tiny elementwise pre-pass outside the kernels
     dsum = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    dsump = jnp.pad(dsum, ((0, 0), (0, 0), (0, sq_p - Sq)))
+    dsump = jnp.pad(dsum, ((0, 0), (0, 0), (0, sq_p - Sq)))[..., None]
 
     common = dict(scale=scale, causal=causal, window=window, softcap=softcap,
                   block_q=block_q, block_k=block_k, q_pos0=q_pos0,
-                  q_len=Sq, kv_len=Sk)
+                  q_len=Sq, kv_len=Sk, precision=dot_precision(q.dtype))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, num_k_blocks=nk, **common),
@@ -181,8 +186,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, hd_p), lambda b, h, qi, ki, g=G: (b, h // g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, dv_p), lambda b, h, qi, ki, g=G: (b, h // g, ki, 0)),
             pl.BlockSpec((1, 1, block_q, dv_p), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd_p), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, sq_p, hd_p), q.dtype),
@@ -202,10 +207,10 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, dv_p), lambda b, kv, ki, gi: (b, kv, ki, 0)),
             pl.BlockSpec((1, 1, block_q, dv_p),
                          lambda b, kv, ki, gi, g=G, n=nq: (b, kv * g + gi // n, gi % n, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, kv, ki, gi, g=G, n=nq: (b, kv * g + gi // n, gi % n)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, kv, ki, gi, g=G, n=nq: (b, kv * g + gi // n, gi % n)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, kv, ki, gi, g=G, n=nq: (b, kv * g + gi // n, gi % n, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, kv, ki, gi, g=G, n=nq: (b, kv * g + gi // n, gi % n, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, block_k, hd_p), lambda b, kv, ki, gi: (b, kv, ki, 0)),

@@ -1,35 +1,63 @@
 """On-arrival clustering distance (paper Eq. 1) as a Pallas TPU kernel.
 
 Computes L1(u, v_c) for one arriving flattened parameter vector ``u``
-against all ``C`` cluster centers. At assigned-architecture scale
-(N = 1e9..4e11 after sharding) this is a pure HBM-bandwidth-bound streaming
-reduction: each (1, block_n) tile of ``u`` and (1, block_n) tile of each
-center is pulled into VMEM once, |u - v| is reduced on the VPU, and a
-(1, 1) fp32 accumulator in the output ref carries the partial sum across
-the sequential inner grid dimension.
+against all ``C`` cluster centers. At assigned-architecture scale this is a
+pure HBM-bandwidth-bound streaming reduction.
 
-Grid: (C, N / block_n), block_n = 64k lanes (512 sublanes x 128 lanes).
+Layout: the flat dim is folded lane-dense into ``(rows, 128)`` (rows a
+multiple of 8), so ``u`` streams as ``(block_r, 128)`` tiles and the
+centers as ``(block_c, block_r, 128)`` tiles — every tile is whole
+(8, 128) vregs. Each grid step folds ``|u - c|`` over groups of 8 rows into
+an ``(8, 128)`` partial-sum tile per center: VPU adds only, accumulated in
+the output block across the sequential row axis. The last 1024 -> 1 sum
+per center runs in XLA on the ``(C, 8, 128)`` partials.
+
+Grid: (C / block_c, rows / block_r), the row axis innermost.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+SUBLANES = 8
+
+
+def row_tiling(n: int, block_n: int) -> tuple[int, int]:
+    """(rows, block_r) for a flat dim of ``n`` laid out as ``(rows, 128)``:
+    one block of every row when they fit in ``block_n`` elements, else the
+    fewest equal blocks of at most ``block_n`` elements, each a multiple of
+    32 rows (the sublane tile of every dtype down to 8 bits)."""
+    rows = math.ceil(n / (SUBLANES * LANES)) * SUBLANES
+    max_r = max(SUBLANES, block_n // LANES)
+    if rows <= max_r:
+        return rows, rows
+    blocks = math.ceil(rows / max_r)
+    block_r = math.ceil(rows / blocks / 32) * 32
+    return block_r * blocks, block_r
+
+
+def to_lanes(x: jax.Array, rows: int) -> jax.Array:
+    """Zero-pad the last dim to ``rows * 128`` and fold it to ``(rows, 128)``
+    (zero padding is exact for L1 and for the lerp, whose pad lanes are
+    sliced off)."""
+    pad = rows * LANES - x.shape[-1]
+    x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+    return x.reshape(*x.shape[:-1], rows, LANES)
+
 
 def _l1_kernel(u_ref, c_ref, o_ref):
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    u = u_ref[...].astype(jnp.float32)
-    c = c_ref[0].astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(jnp.abs(u - c))
+    u = u_ref[...].astype(jnp.float32)  # (block_r, 128)
+    c = c_ref[...].astype(jnp.float32)  # (block_c, block_r, 128)
+    d = jnp.abs(c - u[None])
+    o_ref[...] += d.reshape(c.shape[0], -1, SUBLANES, LANES).sum(axis=1)
 
 
 def l1_distance(
@@ -37,38 +65,26 @@ def l1_distance(
     centers: jax.Array,  # (C, N)
     *,
     block_n: int = 65536,
+    block_c: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
     (N,) = u.shape
     C = centers.shape[0]
-    block_n = min(block_n, max(128, 1 << (N - 1).bit_length()))
-    n_p = math.ceil(N / block_n) * block_n
-    # Zero padding is exact for L1: |0 - 0| contributes nothing.
-    up = jnp.pad(u, (0, n_p - N)).reshape(1, n_p)
-    cp = jnp.pad(centers, ((0, 0), (0, n_p - N)))
-    nk = n_p // block_n
+    rows, block_r = row_tiling(N, block_n)
+    block_c = min(block_c, C)
+    c_p = math.ceil(C / block_c) * block_c
+    up = to_lanes(u, rows)
+    cp = to_lanes(jnp.pad(centers, ((0, c_p - C), (0, 0))), rows)
 
     out = pl.pallas_call(
         _l1_kernel,
-        grid=(C, nk),
+        grid=(c_p // block_c, rows // block_r),
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda c, k: (0, k)),
-            pl.BlockSpec((1, block_n), lambda c, k: (c, k)),
+            pl.BlockSpec((block_r, LANES), lambda c, k: (k, 0)),
+            pl.BlockSpec((block_c, block_r, LANES), lambda c, k: (c, k, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda c, k: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, 1), jnp.float32),
+        out_specs=pl.BlockSpec((block_c, SUBLANES, LANES), lambda c, k: (c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((c_p, SUBLANES, LANES), jnp.float32),
         interpret=interpret,
     )(up, cp)
-    return out[:, 0]
-
-
-def pairwise_l1(
-    vectors: jax.Array,  # (M, N)
-    *,
-    block_n: int = 65536,
-    interpret: bool = False,
-) -> jax.Array:
-    """(M, M) pairwise L1 matrix — used by the ClusterFL baseline and the
-    clustering-quality benchmark. Reuses the streaming kernel row by row."""
-    fn = functools.partial(l1_distance, centers=vectors, block_n=block_n, interpret=interpret)
-    return jax.vmap(lambda row: fn(row))(vectors)
+    return out[:C].sum(axis=(1, 2))

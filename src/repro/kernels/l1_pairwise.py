@@ -8,9 +8,13 @@ where the seed implementation looped the one-vs-many kernel row by row.
 
 Grid: (M / block_m, C / block_c, N / block_n); the innermost n-dimension is
 sequential, so each (block_m, block_c) output tile accumulates its partial
-sums in fp32 across n-steps. The VPU does the |x - c| broadcast reduction
-on a (block_m, block_c, block_n) tile; block sizes keep that tile well
-under VMEM (8 * 8 * 8192 * 4 B = 2 MiB).
+sums in fp32 across n-steps. The centers sit on the output's lanes: a tile
+holds every center when C <= 128 (the full dim), else 128 of them, so the
+output block is always legal and lane-dense. Inside a step the kernel walks
+the tile's centers one row at a time — ``|x - c_j|`` over the
+(block_m, block_n) query tile, a lane reduction to one column, and a select
+into lane ``j`` of the accumulator — so no (block_m, block_c, block_n)
+intermediate is ever built.
 """
 from __future__ import annotations
 
@@ -22,15 +26,21 @@ from jax.experimental import pallas as pl
 
 
 def _pairwise_kernel(x_ref, c_ref, o_ref):
-    ni = pl.program_id(2)
-
-    @pl.when(ni == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)  # (block_m, block_n)
-    c = c_ref[...].astype(jnp.float32)  # (block_c, block_n)
-    o_ref[...] += jnp.sum(jnp.abs(x[:, None, :] - c[None, :, :]), axis=-1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+
+    def one_center(j, acc):
+        c = c_ref[pl.ds(j, 1), :].astype(jnp.float32)  # (1, block_n)
+        d = jnp.sum(jnp.abs(x - c), axis=1, keepdims=True)  # (block_m, 1)
+        return acc + jnp.where(lane == j, d, 0.0)
+
+    o_ref[...] += jax.lax.fori_loop(
+        0, c_ref.shape[0], one_center, jnp.zeros(o_ref.shape, jnp.float32)
+    )
 
 
 def l1_distance_pairwise(
@@ -38,14 +48,14 @@ def l1_distance_pairwise(
     centers: jax.Array,  # (C, N)
     *,
     block_m: int = 8,
-    block_c: int = 8,
-    block_n: int = 8192,
+    block_c: int = 128,
+    block_n: int = 2048,
     interpret: bool = False,
 ) -> jax.Array:
     M, N = xs.shape
     C = centers.shape[0]
     block_m = min(block_m, max(1, 1 << (M - 1).bit_length()))
-    block_c = min(block_c, max(1, 1 << (C - 1).bit_length()))
+    block_c = min(block_c, C)
     block_n = min(block_n, max(128, 1 << (N - 1).bit_length()))
     m_p = math.ceil(M / block_m) * block_m
     c_p = math.ceil(C / block_c) * block_c

@@ -8,41 +8,40 @@ Line 2-6 of Algorithm 1, fused over flattened parameter vectors:
     alpha  = relu(p) / max(p)        (attention map, global-max normalized)
     merged = alpha * v_aux + (1 - alpha) * v_main
 
-A naive jnp composition makes 5 HBM round-trips over N (~1e9..4e11)
-elements; the fused form needs exactly two passes (a max-reduction, then a
-blend that re-reads the three inputs once and writes once) — the minimum
-possible given the global normalizer. Pass 1 accumulates a running max in a
-(1,1) VMEM output ref across the sequential grid; pass 2 is a pure
-elementwise VPU kernel.
+A naive jnp composition makes 5 HBM round-trips over N elements; the fused
+form needs exactly two passes (a max-reduction, then a blend that re-reads
+the three inputs once and writes once) — the minimum possible given the
+global normalizer. The vectors stream lane-dense as ``(rows, 128)`` tiles
+(``l1_distance.row_tiling``). Pass 1 keeps an elementwise running max in an
+(8, 128) output tile across the sequential grid; pass 2 reduces that tile
+to the global max and runs a pure elementwise VPU blend.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.l1_distance import LANES, SUBLANES, row_tiling, to_lanes
+
 _NEG = -3.4e38
 
 
 def _max_kernel(vm_ref, va_ref, vt_ref, o_ref):
-    ki = pl.program_id(0)
-
-    @pl.when(ki == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, _NEG)
 
     vm = vm_ref[...].astype(jnp.float32)
     p = (va_ref[...].astype(jnp.float32) - vm) * (vt_ref[...].astype(jnp.float32) - vm)
-    o_ref[0, 0] = jnp.maximum(o_ref[0, 0], jnp.max(p))
+    o_ref[...] = jnp.maximum(o_ref[...], p.reshape(-1, SUBLANES, LANES).max(axis=0))
 
 
 def _blend_kernel(vm_ref, va_ref, vt_ref, pmax_ref, o_ref):
     vm = vm_ref[...].astype(jnp.float32)
     va = va_ref[...].astype(jnp.float32)
     p = (va - vm) * (vt_ref[...].astype(jnp.float32) - vm)
-    denom = jnp.maximum(pmax_ref[0, 0], 1e-12)
+    denom = jnp.maximum(jnp.max(pmax_ref[...]), 1e-12)
     alpha = jnp.maximum(p, 0.0) / denom
     o_ref[...] = (alpha * va + (1.0 - alpha) * vm).astype(o_ref.dtype)
 
@@ -56,12 +55,11 @@ def merge_attention(
     interpret: bool = False,
 ) -> jax.Array:
     (N,) = v_main.shape
-    block_n = min(block_n, max(128, 1 << (N - 1).bit_length()))
-    n_p = math.ceil(N / block_n) * block_n
-    pad = lambda x: jnp.pad(x, (0, n_p - N)).reshape(1, n_p)
-    vm, va, vt = pad(v_main), pad(v_aux), pad(v_trained)
-    nk = n_p // block_n
-    vec_spec = pl.BlockSpec((1, block_n), lambda k: (0, k))
+    rows, block_r = row_tiling(N, block_n)
+    vm, va, vt = (to_lanes(x, rows) for x in (v_main, v_aux, v_trained))
+    nk = rows // block_r
+    vec_spec = pl.BlockSpec((block_r, LANES), lambda k: (k, 0))
+    max_spec = pl.BlockSpec((SUBLANES, LANES), lambda k: (0, 0))
 
     # Padding note: padded lanes give p = 0, which only matters if every real
     # p < 0; relu() zeroes those lanes in the blend anyway, so exactness holds.
@@ -69,17 +67,17 @@ def merge_attention(
         _max_kernel,
         grid=(nk,),
         in_specs=[vec_spec, vec_spec, vec_spec],
-        out_specs=pl.BlockSpec((1, 1), lambda k: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=max_spec,
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.float32),
         interpret=interpret,
     )(vm, va, vt)
 
     merged = pl.pallas_call(
         _blend_kernel,
         grid=(nk,),
-        in_specs=[vec_spec, vec_spec, vec_spec, pl.BlockSpec((1, 1), lambda k: (0, 0))],
+        in_specs=[vec_spec, vec_spec, vec_spec, max_spec],
         out_specs=vec_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n_p), v_main.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), v_main.dtype),
         interpret=interpret,
     )(vm, va, vt, pmax)
-    return merged[0, :N]
+    return merged.reshape(-1)[:N]

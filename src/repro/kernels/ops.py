@@ -1,9 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On TPU these dispatch to the compiled kernels; elsewhere (this CPU
-container, unit tests) they run the same kernel bodies in interpret mode
-or fall back to the jnp oracle for speed. The protocol layer calls only
-these wrappers, so swapping the backend never touches coordination code.
+``REPRO_KERNELS`` (read per call) picks the backend: ``auto`` (default)
+compiles the Pallas kernels on a TPU and runs the jnp oracles in
+``kernels/ref.py`` on any other backend; ``pallas`` forces the kernel bodies
+(interpreted on the CPU backend, compiled elsewhere); ``ref`` forces the
+oracles. The protocol layer calls only these wrappers, so swapping the
+backend never touches coordination code.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ import numpy as np
 
 from repro.kernels import plane_sharded, ref
 from repro.kernels.assign_lerp import assign_and_lerp as _assign_lerp_kernel
+from repro.kernels.assign_lerp import select_lerp as _select_lerp_kernel
 from repro.kernels.chi2_feedback import chi2_feedback as _chi2_kernel
 from repro.kernels.chi2_feedback import chi2_feedback_segmented as _chi2_seg_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
@@ -24,22 +27,18 @@ from repro.kernels.l1_pairwise import l1_distance_pairwise as _l1_pairwise_kerne
 from repro.kernels.merge_attention import merge_attention as _merge_kernel
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
-_FORCE = os.environ.get("REPRO_KERNELS", "auto")  # auto | pallas | ref
-
-
 def _use_pallas() -> bool:
-    if _FORCE == "pallas":
-        return True
-    if _FORCE == "ref":
-        return False
-    return _on_tpu()
+    force = os.environ.get("REPRO_KERNELS") or "auto"
+    if force not in ("auto", "pallas", "ref"):
+        raise ValueError(f"REPRO_KERNELS={force!r}: expected auto, pallas or ref")
+    if force == "auto":
+        return jax.default_backend() == "tpu"
+    return force == "pallas"
+
+
+def _interpret() -> bool:
+    """Pallas kernels run interpreted on the CPU backend and nowhere else."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "window", "softcap", "q_pos0"))
@@ -47,7 +46,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None, window=None, softcap=No
     if _use_pallas():
         return _flash_kernel(
             q, k, v, causal=causal, scale=scale, window=window, softcap=softcap,
-            q_pos0=q_pos0, interpret=not _on_tpu(),
+            q_pos0=q_pos0, interpret=_interpret(),
         )
     return ref.flash_attention_ref(
         q, k, v, causal=causal, scale=scale, window=window, softcap=softcap, q_pos0=q_pos0
@@ -58,9 +57,8 @@ def flash_attention(q, k, v, *, causal=True, scale=None, window=None, softcap=No
 # Trainable attention: flash forward + flash backward kernels via custom_vjp.
 # This is what the model's train/prefill path calls — it is the difference
 # between O(S^2) attention HBM traffic (materialized score matrices, the
-# paper-naive baseline measured with REPRO_KERNELS=ref) and the
-# O(S^2 * d / block) streaming traffic of the fused kernels (see
-# EXPERIMENTS.md §Perf iteration 1).
+# REPRO_KERNELS=ref oracle) and the O(S^2 * d / block) streaming traffic of
+# the fused kernels.
 # ---------------------------------------------------------------------------
 from repro.kernels.flash_attention import flash_attention_with_lse as _flash_fwd_lse
 from repro.kernels.flash_attention_bwd import flash_attention_bwd as _flash_bwd
@@ -118,7 +116,7 @@ def _local_attention(q, k, v, *, causal, scale, window, softcap, q_pos0):
             softcap=softcap, q_pos0=q_pos0,
         )
     return _attention_trainable(
-        q, k, v, causal, scale, window, softcap, q_pos0, not _on_tpu()
+        q, k, v, causal, scale, window, softcap, q_pos0, _interpret()
     )
 
 
@@ -183,20 +181,20 @@ def attention(q, k, v, *, causal=True, scale=None, window=None, softcap=None, q_
 @jax.jit
 def l1_distance(u, centers):
     if _use_pallas():
-        return _l1_kernel(u, centers, interpret=not _on_tpu())
+        return _l1_kernel(u, centers, interpret=_interpret())
     return ref.l1_distance_ref(u, centers)
 
 
 @jax.jit
 def merge_attention(v_main, v_aux, v_trained):
     if _use_pallas():
-        return _merge_kernel(v_main, v_aux, v_trained, interpret=not _on_tpu())
+        return _merge_kernel(v_main, v_aux, v_trained, interpret=_interpret())
     return ref.merge_attention_ref(v_main, v_aux, v_trained)[0]
 
 
 def _chi2_local(f_pred, f_true, s_soft):
     if _use_pallas():
-        return _chi2_kernel(f_pred, f_true, s_soft, interpret=not _on_tpu())
+        return _chi2_kernel(f_pred, f_true, s_soft, interpret=_interpret())
     return ref.chi2_feedback_ref(f_pred, f_true, s_soft)
 
 
@@ -355,19 +353,19 @@ def _to_mesh_dim(mesh, dim_axis, *arrays):
 
 def _l1_pairwise_local(xs, centers):
     if _use_pallas():
-        return _l1_pairwise_kernel(xs, centers, interpret=not _on_tpu())
+        return _l1_pairwise_kernel(xs, centers, interpret=_interpret())
     return ref.l1_distance_pairwise_ref(xs, centers)
 
 
 def _l1_local(u, centers):
     if _use_pallas():
-        return _l1_kernel(u, centers, interpret=not _on_tpu())
+        return _l1_kernel(u, centers, interpret=_interpret())
     return ref.l1_distance_ref(u, centers)
 
 
 def _chi2_seg_local(f_pred, f_true, s_soft, onehot):
     if _use_pallas():
-        return _chi2_seg_kernel(f_pred, f_true, s_soft, onehot, interpret=not _on_tpu())
+        return _chi2_seg_kernel(f_pred, f_true, s_soft, onehot, interpret=_interpret())
     return ref.chi2_feedback_segmented_ref(f_pred, f_true, s_soft, onehot)
 
 
@@ -406,15 +404,38 @@ def l1_distance_pairwise(xs, centers, *, mesh=None, axis="plane", dim_axis="mode
 @functools.partial(jax.jit, static_argnames=("beta",))
 def _assign_lerp_single(u, centers, beta):
     if _use_pallas():
-        return _assign_lerp_kernel(u, centers, beta, interpret=not _on_tpu())
+        return _assign_lerp_kernel(u, centers, beta, interpret=_interpret())
     return ref.assign_and_lerp_ref(u, centers, beta)
 
 
-@functools.partial(jax.jit, static_argnames=("beta", "valid_rows", "mesh", "axis", "dim_axis"))
-def _assign_lerp_mesh(u, centers, beta, valid_rows, mesh, axis, dim_axis=None):
-    return plane_sharded.assign_lerp_sharded(
-        u, centers, beta, mesh, axis, _l1_local, valid_rows=valid_rows,
-        dim_axis=dim_axis,
+@functools.partial(jax.jit, static_argnames=("beta",))
+def _select_lerp_single(row, u, beta):
+    return _select_lerp_kernel(row[None], u, jnp.int32(0), beta, interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("beta",))
+def _lerp_products(row, u, beta):
+    return (1.0 - beta) * row.astype(jnp.float32), beta * u.astype(jnp.float32)
+
+
+_add = jax.jit(jnp.add)
+
+
+def _lerp_row(row, u, beta):
+    """The blend of ``_assign_lerp_single`` for one given row: the Pallas
+    select-lerp kernel, or the oracle's two products and their sum as
+    separate programs. XLA drops optimization barriers on some paths (the
+    SPMD partitioner, small programs), and a fused multiply-add rounds
+    once, an ulp off the two-op blend."""
+    if _use_pallas():
+        return _select_lerp_single(row, u, beta)
+    return _add(*_lerp_products(row, u, beta))
+
+
+@functools.partial(jax.jit, static_argnames=("valid_rows", "mesh", "axis", "dim_axis"))
+def _assign_mesh(u, centers, valid_rows, mesh, axis, dim_axis=None):
+    return plane_sharded.assign_sharded(
+        u, centers, mesh, axis, _l1_local, valid_rows=valid_rows, dim_axis=dim_axis,
     )
 
 
@@ -423,22 +444,26 @@ def assign_and_lerp(u, centers, beta, *, mesh=None, axis="plane", dim_axis="mode
 
     With a plane mesh, the C center rows shard over ``axis``; distances
     all_gather, the argmin replicates, and the winning row is fetched with
-    a one-hot psum — the full center matrix never moves. With a model axis
-    whose extent divides N, the dim shards too: per-shard partial L1 sums
-    psum into the distances and each model shard blends only its own chunk
-    of the winning row."""
+    a one-hot psum — the full center matrix never moves — and blended on
+    the mesh's first device. With a model axis whose extent divides N, the dim
+    shards too: per-shard partial L1 sums psum into the distances."""
     ds = _dim_shards(mesh, dim_axis, u.shape[-1]) if mesh is not None else 1
     if _mesh_active(mesh, axis) or ds > 1:
         C = centers.shape[0]
         da = dim_axis if ds > 1 else None
         centers = _to_mesh_rows(mesh, axis, centers, dim_axis=da)
         if da is not None:
-            (u,) = _to_mesh_dim(mesh, da, u)
+            (u_mesh,) = _to_mesh_dim(mesh, da, u)
         else:
-            (u,) = _to_mesh(mesh, u)
-        return _assign_lerp_mesh(
-            u, centers, beta, valid_rows=C, mesh=mesh, axis=axis, dim_axis=da
+            (u_mesh,) = _to_mesh(mesh, u)
+        dists, idx, row = _assign_mesh(
+            u_mesh, centers, valid_rows=C, mesh=mesh, axis=axis, dim_axis=da
         )
+        # the blend runs outside the SPMD program (see
+        # plane_sharded.assign_sharded), bitwise the unsharded blend, on the
+        # device where the plane keeps its small reads
+        dev = mesh.devices.flat[0]
+        return dists, idx, _lerp_row(jax.device_put(row, dev), jax.device_put(u, dev), beta)
     return _assign_lerp_single(u, centers, beta)
 
 

@@ -10,11 +10,12 @@ answer with one collective:
   * ``l1_pairwise_sharded`` — query rows shard over ``plane``; every shard
     scores its rows against the (replicated) centers. No reduction: the
     (M, C) output is row-sharded and reassembles on exit.
-  * ``assign_lerp_sharded`` — center rows shard over ``plane``; local
+  * ``assign_sharded`` — center rows shard over ``plane``; local
     distance vectors ``all_gather`` into the full (C,) vector, the argmin
     is computed redundantly on every shard, and the winning center row is
     recovered with a one-hot ``psum`` (only its owner contributes), so the
-    blend never moves the whole center matrix.
+    blend (run by the caller on one device) never moves the whole center
+    matrix.
   * ``chi2_all_sharded`` — member rows shard over ``plane``; per-cluster
     segment sums are partial per shard and ``psum`` into the global sums.
   * ``chi2_rows_sharded`` — the dissolve/expand probe matrix: rows shard
@@ -47,9 +48,8 @@ never materializes whole anywhere:
     (last-ulp vs the single-device flat reduction; the R×M subprocess
     trajectory harness in tests/test_model_axis_plane.py pins that the
     server's *decisions* and blended centers stay identical);
-  * the assign blend is elementwise, so each model shard blends only its
-    own dim chunk of the winning row — per-element arithmetic unchanged,
-    bitwise-identical to the single-device blend;
+  * the winning row of the assign comes back dim-sharded; the caller's
+    single-device blend is bitwise the single-device plane's;
   * the chi2 kernels spread their member/probe *rows* over both axes
     (the feedback operands have no model dim — per-row arithmetic stays
     shard-local and bitwise; segment sums psum over both axes).
@@ -66,11 +66,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-try:  # stable path in newer jax; experimental in the pinned 0.4.x
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -92,7 +88,7 @@ def l1_pairwise_sharded(
             mesh=mesh,
             in_specs=(P(axis, None), P(None, None)),
             out_specs=P(axis, None),
-            check_rep=False,
+            check_vma=False,
         )(xs, centers)
 
     def body(x_local, c_local):
@@ -103,31 +99,31 @@ def l1_pairwise_sharded(
         mesh=mesh,
         in_specs=(P(axis, dim_axis), P(None, dim_axis)),
         out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )(xs, centers)
 
 
-def assign_lerp_sharded(
+def assign_sharded(
     u: jax.Array,  # (N,) arriving upload, replicated
     centers: jax.Array,  # (C_padded, N) center rows, sharded over ``axis``
-    beta: float,
     mesh: jax.sharding.Mesh,
     axis: str,
     local_dist_fn: Callable[[jax.Array, jax.Array], jax.Array],
     valid_rows: int | None = None,
     dim_axis: str | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Sharded fused Eq. 1 argmin + blend: (dists (C,), idx (), blended (N,)).
+    """Sharded Eq. 1 argmin: (dists (C,), idx (), winning row (N,)).
 
     ``valid_rows`` is the true center count; the shard-padding rows above
     it are masked to ``+inf`` so they can never win the argmin. With
     ``dim_axis`` the upload and the center rows arrive dim-chunked: the
-    kernel body scores each shard's chunk (a partial L1 sum), a ``psum``
-    over ``dim_axis`` completes the distances, and after the replicated
-    argmin each model shard blends only its own chunk of the winning row
-    (elementwise — bitwise-identical per element to the full-row blend)."""
+    kernel body scores each shard's chunk (a partial L1 sum) and a ``psum``
+    over ``dim_axis`` completes the distances; the winning row comes back
+    dim-sharded. The blend itself is left to the caller, which runs the
+    single-device blend body on one device: the SPMD partitioner drops the
+    optimization barrier that keeps that blend's two products apart, so a
+    blend inside this program would be an FMA, an ulp off the plane's."""
     C = valid_rows if valid_rows is not None else centers.shape[0]
-    cp = centers
 
     def body(u_full, c_local):
         rows_local = c_local.shape[0]
@@ -144,17 +140,16 @@ def assign_lerp_sharded(
         row = jax.lax.dynamic_index_in_dim(c_local, li, 0, keepdims=False)
         owned = (idx >= row0) & (idx < row0 + rows_local)
         row = jax.lax.psum(jnp.where(owned, row, 0.0), axis)
-        blended = (1.0 - beta) * row.astype(jnp.float32) + beta * u_full.astype(jnp.float32)
-        return d_full, idx, blended
+        return d_full, idx, row
 
-    d_full, idx, blended = shard_map(
+    d_full, idx, row = shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dim_axis), P(axis, dim_axis)),
         out_specs=(P(None), P(), P(dim_axis)),
-        check_rep=False,
-    )(u, cp)
-    return d_full[:C], idx, blended
+        check_vma=False,
+    )(u, centers)
+    return d_full[:C], idx, row
 
 
 def chi2_rows_sharded(
@@ -179,7 +174,7 @@ def chi2_rows_sharded(
         mesh=mesh,
         in_specs=(P(rows, None),) * 3,
         out_specs=P(rows),
-        check_rep=False,
+        check_vma=False,
     )(f_pred, f_true, s_soft)
 
 
@@ -210,5 +205,5 @@ def chi2_all_sharded(
         mesh=mesh,
         in_specs=(P(rows, None),) * 4,
         out_specs=(P(rows), P(None)),
-        check_rep=False,
+        check_vma=False,
     )(f_pred, f_true, s_soft, seg_onehot)

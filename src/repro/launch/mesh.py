@@ -20,6 +20,7 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e roofline constants (per chip) — used by benchmarks/bench_roofline.
 PEAK_FLOPS_BF16 = 197e12  # FLOP/s
@@ -27,16 +28,23 @@ HBM_BW = 819e9  # bytes/s
 ICI_BW = 50e9  # bytes/s per link
 
 
+def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes: the plane, fleet and model code
+    place operands with ``NamedSharding``/``shard_map`` and leave the rest to
+    the partitioner, which Explicit axes (the ``make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_smoke_mesh() -> jax.sharding.Mesh:
     """1-device mesh with the production axis names — smoke tests and the
     quickstart use it so the same shardings lower everywhere."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _make_mesh((1, 1), ("data", "model"))
 
 
 def make_plane_mesh(row_shards: int | None = None, *, dim_shards: int = 1) -> jax.sharding.Mesh:
@@ -51,8 +59,8 @@ def make_plane_mesh(row_shards: int | None = None, *, dim_shards: int = 1) -> ja
     if row_shards is None:
         row_shards = n // dim_shards
     if dim_shards == 1:
-        return jax.make_mesh((row_shards,), ("plane",))
-    return jax.make_mesh((row_shards, dim_shards), ("plane", "model"))
+        return _make_mesh((row_shards,), ("plane",))
+    return _make_mesh((row_shards, dim_shards), ("plane", "model"))
 
 
 def _mesh_from_spec(spec: str) -> jax.sharding.Mesh | None:

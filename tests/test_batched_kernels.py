@@ -112,7 +112,7 @@ def test_chi2_segmented_crosses_m_blocks():
 # ------------------------------------------- ops wrappers, both backends
 @pytest.fixture(params=["ref", "pallas"])
 def force_backend(request, monkeypatch):
-    monkeypatch.setattr(ops, "_FORCE", request.param)
+    monkeypatch.setenv("REPRO_KERNELS", request.param)
     return request.param
 
 

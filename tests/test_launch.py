@@ -148,7 +148,7 @@ def test_hlo_cost_collectives_bucketed():
     def f(x):
         return jax.lax.psum(x, "data")
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     x = jnp.ones((4, 8), jnp.float32)
     sm = shard_map(

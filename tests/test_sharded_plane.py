@@ -364,3 +364,50 @@ def test_sharded_server_trajectory_parity_on_forced_8_device_host():
     )
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "PARITY-OK" in proc.stdout
+
+
+_SCATTER_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from repro.core.plane import ParameterPlane
+    from repro.launch.mesh import make_plane_mesh
+
+    assert len(jax.devices()) == 4
+    mesh = make_plane_mesh()
+    plane = ParameterPlane({"w": jnp.zeros((24,))}, capacity=8, mesh=mesh)
+    rows = [plane.alloc(jnp.full((24,), float(i + 1))) for i in range(3)]
+    plane.flush()  # multi-row scatter into the row-sharded buffer
+    bulk = plane.alloc_many(2)
+    plane.write_rows(bulk, jnp.full((2, 24), 7.0))
+    buf = plane.matrix()  # bulk scatter
+    assert buf.sharding.spec[0] == "plane", buf.sharding
+    for i, r in enumerate(rows):
+        np.testing.assert_array_equal(np.asarray(buf[r]), i + 1.0)
+    np.testing.assert_array_equal(np.asarray(buf[bulk[1]]), 7.0)
+    print("SCATTER-OK")
+    """
+)
+
+
+def test_plane_mesh_scatters_rows_on_4_device_host():
+    """``make_plane_mesh`` meshes take the plane's row scatters: under
+    Explicit mesh axes (``jax.make_mesh``'s default) the scatter into a
+    row-sharded buffer is refused with a ShardingTypeError. Runs in a
+    subprocess because the device count is fixed at jax init."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH", "")) if p
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _SCATTER_SCRIPT],
+        capture_output=True,
+        text=True,
+        timeout=600,
+        env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
+    assert "SCATTER-OK" in proc.stdout
