@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.pytrees import flatten_spec
+from repro.common.tracing import fetch
 from repro.core.server import Downlink
 from repro.core.staleness import StalenessTracker
 
@@ -110,7 +111,7 @@ class FedAsyn:
         ])
         us = jnp.stack([self.spec.flatten(p) for _, p, _, _, _ in batch])
         self._vec, models = _lerp_chain(self._vec, us, ws)
-        models_np = np.asarray(jax.device_get(models))
+        models_np = np.asarray(fetch(models, "chain"))
         models_np.flags.writeable = False  # leaves are views: freeze
         out = []
         for j, (cid, _p, _bv, _n, _t) in enumerate(batch):

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.tracing import fetch
+
 PyTree = Any
 HIDDEN = 128
 NUM_LAYERS = 2
@@ -206,7 +208,7 @@ class BroadcastPredictor:
         if len(self.records) < 2:  # cold start: rule-based fallback
             want = accumulated_gap > fallback_threshold * self.scale
         else:
-            want = bool(_rnn_want(self.params, self._seq()))
+            want = bool(fetch(_rnn_want(self.params, self._seq()), "decide"))
         if want:
             self.broadcasts += 1
         return want

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.pytrees import tree_flat_vector, tree_lerp, tree_unflatten_vector
+from repro.common.tracing import fetch
 from repro.core.plane import ParameterPlane
 from repro.kernels import ops as K
 
@@ -187,13 +188,13 @@ class Cluster:
         candidates.append(self._bcast_row if self._plane is not None else self._bcast_tree)
         for cand in candidates:
             if self._plane is not None:
-                if not bool(np.isfinite(np.asarray(self._plane.row(cand))).all()):
+                if not bool(np.isfinite(fetch(self._plane.row(cand), "rollback")).all()):
                     continue  # this snapshot is itself corrupt: go older
                 self._plane.copy_row(cand, self._row)
                 self._center_cache = None
             else:
                 if cand is None or not bool(
-                    np.isfinite(np.asarray(tree_flat_vector(cand))).all()
+                    np.isfinite(fetch(tree_flat_vector(cand), "rollback")).all()
                 ):
                     continue
                 self._center_tree = cand
@@ -356,7 +357,7 @@ class DynamicClustering:
         cids = sorted(self.clusters)
         u = tree_flat_vector(update)
         centers = jnp.stack([tree_flat_vector(self.clusters[c].center) for c in cids])
-        dists = np.asarray(K.l1_distance(u, centers))
+        dists = fetch(K.l1_distance(u, centers), "assign")
         cid = cids[int(np.argmin(dists))]
         if prev is not None and prev in self.clusters and prev != cid:
             d_prev = dists[cids.index(prev)]
@@ -384,7 +385,7 @@ class DynamicClustering:
         kw = self._kernel_mesh_kwargs(len(cids))
         centers = self.plane.rows([self.clusters[c]._row for c in cids], on_mesh=bool(kw))
         dists_d, _amin, blended = K.assign_and_lerp(u, centers, self.mix_rate, **kw)
-        dists = np.asarray(dists_d)  # one host sync; argmin re-read from it
+        dists = fetch(dists_d, "assign")  # one host sync; argmin re-read from it
         cid = cids[int(np.argmin(dists))]
         # the blend is only valid against the center version it was computed
         # from; aggregate() re-checks under the branch write lock
@@ -490,12 +491,12 @@ class DynamicClustering:
         if self.backend == "plane":
             kw = self._kernel_mesh_kwargs(len(cids))
             vecs = self.plane.rows([self.clusters[c]._row for c in cids], on_mesh=bool(kw))
-            dmat = np.asarray(K.l1_distance_pairwise(vecs, vecs, **kw))
+            dmat = fetch(K.l1_distance_pairwise(vecs, vecs, **kw), "pairwise_l1")
         else:
             vecs = jnp.stack([tree_flat_vector(self.clusters[c].center) for c in cids])
             dmat = np.zeros((len(cids), len(cids)))
             for i in range(len(cids)):
-                dmat[i] = np.asarray(K.l1_distance(vecs[i], vecs))
+                dmat[i] = fetch(K.l1_distance(vecs[i], vecs), "pairwise_l1")
         off = dmat[~np.eye(len(cids), dtype=bool)]
         median = float(np.median(off))
         dmat = dmat.copy()
@@ -542,7 +543,7 @@ class DynamicClustering:
             centers = self.plane.rows(
                 [self.clusters[c]._row for c in cids], on_mesh=bool(kw)
             )
-            D = np.asarray(K.l1_distance_pairwise(U, centers, **kw))
+            D = fetch(K.l1_distance_pairwise(U, centers, **kw), "pairwise_l1")
             for (m, cid), d in zip(flagged, D):
                 best = cids[int(np.argmin(d))]
                 if best != cid and d[cids.index(best)] < 0.9 * d[cids.index(cid)]:
@@ -552,7 +553,7 @@ class DynamicClustering:
         centers = jnp.stack([tree_flat_vector(self.clusters[c].center) for c in cids])
         for m, cid in flagged:
             u = tree_flat_vector(uploads[m])
-            d = np.asarray(K.l1_distance(u, centers))
+            d = fetch(K.l1_distance(u, centers), "pairwise_l1")
             best = cids[int(np.argmin(d))]
             if best != cid and d[cids.index(best)] < 0.9 * d[cids.index(cid)]:
                 self._move(m, best)

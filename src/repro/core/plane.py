@@ -57,7 +57,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.common import tracing
 from repro.common.pytrees import flatten_spec
+from repro.common.tracing import span
 
 PyTree = Any
 
@@ -277,45 +279,47 @@ class ParameterPlane:
         for r in ids:
             if r not in self._used:
                 raise KeyError(f"row {r} is not allocated")
-        matrix = jnp.asarray(matrix, self.dtype)
-        if matrix.shape != (len(ids), self.dim):
-            raise ValueError(f"expected ({len(ids)}, {self.dim}) matrix, got {matrix.shape}")
-        # per-row staged values for these rows are older than this matrix
-        for r in ids:
-            self._dirty.pop(r, None)
-        if self._bulk:
-            # keep the staging list bounded at one live matrix: cached-view
-            # reads patch in place without flushing, so without this an
-            # eval-tick producer would grow _bulk by one matrix per tick
-            self.flush()
-        self._bulk.append((ids, {r: i for i, r in enumerate(ids)}, self._localize(matrix)))
-        id_set = set(ids)
-        for key in self._views:
-            hit = id_set.intersection(key[0])
-            if hit:
-                self._view_stale[key].update(hit)
+        with span("plane/stage", rows=len(ids)):
+            matrix = jnp.asarray(matrix, self.dtype)
+            if matrix.shape != (len(ids), self.dim):
+                raise ValueError(f"expected ({len(ids)}, {self.dim}) matrix, got {matrix.shape}")
+            # per-row staged values for these rows are older than this matrix
+            for r in ids:
+                self._dirty.pop(r, None)
+            if self._bulk:
+                # keep the staging list bounded at one live matrix: cached-view
+                # reads patch in place without flushing, so without this an
+                # eval-tick producer would grow _bulk by one matrix per tick
+                self.flush()
+            self._bulk.append((ids, {r: i for i, r in enumerate(ids)}, self._localize(matrix)))
+            id_set = set(ids)
+            for key in self._views:
+                hit = id_set.intersection(key[0])
+                if hit:
+                    self._view_stale[key].update(hit)
 
     def flush(self) -> None:
         if not self._dirty and not self._bulk:
             return
-        for ids, _, mat in self._bulk:
-            self._buf = _scatter_rows(
-                self._buf, jnp.asarray(ids, jnp.int32), self._replicate(mat)
-            )
-        self._bulk = []
-        if not self._dirty:
+        with span("plane/flush") as sp:
+            if tracing.on():
+                sp.set_metadata(rows=len(self._dirty) + sum(len(b[0]) for b in self._bulk))
+            for ids, _, mat in self._bulk:
+                self._buf = _scatter_rows(
+                    self._buf, jnp.asarray(ids, jnp.int32), self._replicate(mat)
+                )
+            self._bulk = []
+            if self._dirty:
+                order = sorted(self._dirty)
+                if len(order) == 1:
+                    val = self._replicate(self._dirty[order[0]])
+                    self._buf = _set_row(self._buf, jnp.int32(order[0]), val)
+                else:
+                    rows = jnp.asarray(order, jnp.int32)
+                    vals = self._replicate(jnp.stack([self._dirty[r] for r in order]))
+                    self._buf = _scatter_rows(self._buf, rows, vals)
+                self._dirty.clear()
             self._buf = self._place(self._buf)
-            return
-        order = sorted(self._dirty)
-        if len(order) == 1:
-            val = self._replicate(self._dirty[order[0]])
-            self._buf = _set_row(self._buf, jnp.int32(order[0]), val)
-        else:
-            rows = jnp.asarray(order, jnp.int32)
-            vals = self._replicate(jnp.stack([self._dirty[r] for r in order]))
-            self._buf = _scatter_rows(self._buf, rows, vals)
-        self._buf = self._place(self._buf)
-        self._dirty.clear()
 
     def _replicate(self, v: jax.Array) -> jax.Array:
         """Move a staged value onto the mesh before it meets the sharded

@@ -16,13 +16,16 @@ Per arriving update:
 from __future__ import annotations
 
 import dataclasses
+from collections import Counter
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import tracing
 from repro.common.pytrees import tree_flat_vector, tree_l1
+from repro.common.tracing import fetch, span
 from repro.core.broadcast import (
     BroadcastPredictor,
     build_seq,
@@ -48,6 +51,7 @@ class _PredictorPlan:
 
     wants: dict  # step index -> planned decide() outcome
     new_params: dict  # cid -> batched-chain final RNN params (device)
+    launches: int  # fused chain launches the plan made
 
 
 @dataclasses.dataclass
@@ -225,7 +229,7 @@ class EchoPFLServer:
             self.clustering.aggregate(cid, params)
             c = self.clustering.clusters[cid]
             return c.center if plane is None else c.center_vec
-        branch.push(client_id, merge_fn, f"upload from {client_id} (staleness {staleness})")
+        branch.push(merge_fn)
 
         # 3b. late poison detection (guard only): a non-finite or
         # MAD-blown post-blend center norm vetoes the blend — roll back
@@ -244,13 +248,13 @@ class EchoPFLServer:
         #    label for the previous decision (Eq. 4)
         if pred is not None:
             if plane is None:
-                change = float(tree_l1(cluster.center, prev_center))
+                change = float(fetch(tree_l1(cluster.center, prev_center), "gap"))
             else:
-                change = float(l1_vec(cluster.center_vec, prev_center))
+                change = float(fetch(l1_vec(cluster.center_vec, prev_center), "gap"))
             if plane is None:
-                gap_before = float(tree_l1(prev_center, cluster.last_broadcast_center))
+                gap_before = float(fetch(tree_l1(prev_center, cluster.last_broadcast_center), "gap"))
             else:
-                gap_before = float(l1_vec(prev_center, cluster.broadcast_vec))
+                gap_before = float(fetch(l1_vec(prev_center, cluster.broadcast_vec), "gap"))
             # Ground truth for the decision made before this upload (Eq. 4,
             # with the sign read per the Sec. 5.2.1 text rule): the realized
             # model change exceeding the accumulated gap since the last
@@ -267,9 +271,9 @@ class EchoPFLServer:
         # 6. on-demand broadcast to the rest of the cluster
         if pred is not None and cluster.size > 1:
             if plane is None:
-                gap = float(tree_l1(cluster.center, cluster.last_broadcast_center))
+                gap = float(fetch(tree_l1(cluster.center, cluster.last_broadcast_center), "gap"))
             else:
-                gap = float(l1_vec(cluster.center_vec, cluster.broadcast_vec))
+                gap = float(fetch(l1_vec(cluster.center_vec, cluster.broadcast_vec), "gap"))
             self._decisions += 1
             if pred.decide(gap):
                 self._rnn_broadcasts += 1
@@ -317,7 +321,8 @@ class EchoPFLServer:
                 or not self.enable_clustering
                 or len(cl.clusters) < cl.num_initial
             ):
-                out.append(self.handle_upload(*batch[i]))
+                with span("ingest/single", reason="seeding"):
+                    out.append(self.handle_upload(*batch[i]))
                 i += 1
                 continue
             # segment: consecutive distinct clients
@@ -327,7 +332,8 @@ class EchoPFLServer:
                 seen.add(batch[j][0])
                 j += 1
             if j - i < 2:
-                out.append(self.handle_upload(*batch[i]))
+                with span("ingest/single", reason="repeat"):
+                    out.append(self.handle_upload(*batch[i]))
                 i += 1
                 continue
             seg_out, consumed = self._handle_upload_segment(batch[i:j])
@@ -348,57 +354,59 @@ class EchoPFLServer:
         pos = {c: k for k, c in enumerate(cid_order)}
         S = len(seg)
 
-        # one flatten per upload, one stacked write into the upload rows
-        # (the same vectors the per-event path writes one at a time)
-        U = jnp.stack([plane.from_pytree(item[1]) for item in seg])
-        upload_rows = []
-        for item in seg:
-            row = self._upload_rows.get(item[0])
-            if row is None:
-                row = self._upload_rows[item[0]] = plane.alloc()
-            upload_rows.append(row)
-        plane.write_rows(upload_rows, U)
+        with span("ingest/chain", steps=S, centers=len(cid_order)) as chain:
+            # one flatten per upload, one stacked write into the upload rows
+            # (the same vectors the per-event path writes one at a time)
+            U = jnp.stack([plane.from_pytree(item[1]) for item in seg])
+            upload_rows = []
+            for item in seg:
+                row = self._upload_rows.get(item[0])
+                if row is None:
+                    row = self._upload_rows[item[0]] = plane.alloc()
+                upload_rows.append(row)
+            plane.write_rows(upload_rows, U)
 
-        prev_idx, forced_idx = [], []
-        for item in seg:
-            prev = cl.assignment.get(item[0])
-            alive = prev is not None and prev in cl.clusters
-            pf = alive and item[0] in cl.clusters[prev].partial_finetune
-            prev_idx.append(pos[prev] if alive else -1)
-            forced_idx.append(pos[prev] if pf else -1)
+            prev_idx, forced_idx = [], []
+            for item in seg:
+                prev = cl.assignment.get(item[0])
+                alive = prev is not None and prev in cl.clusters
+                pf = alive and item[0] in cl.clusters[prev].partial_finetune
+                prev_idx.append(pos[prev] if alive else -1)
+                forced_idx.append(pos[prev] if pf else -1)
 
-        P = 1 << (S - 1).bit_length()  # pad the scan length: O(log window) jit cache
-        valid = [True] * S + [False] * (P - S)
-        if P != S:
-            U = jnp.concatenate([U, jnp.broadcast_to(U[:1], (P - S, U.shape[1]))])
-            prev_idx += [-1] * (P - S)
-            forced_idx += [-1] * (P - S)
+            P = 1 << (S - 1).bit_length()  # pad the scan length: O(log window) jit cache
+            valid = [True] * S + [False] * (P - S)
+            if P != S:
+                U = jnp.concatenate([U, jnp.broadcast_to(U[:1], (P - S, U.shape[1]))])
+                prev_idx += [-1] * (P - S)
+                forced_idx += [-1] * (P - S)
 
-        C0 = plane.rows([cl.clusters[c]._row for c in cid_order])
-        B0 = plane.rows([cl.clusters[c]._bcast_row for c in cid_order])
-        Cn = len(cid_order)
-        Cp = 1 << (Cn - 1).bit_length()  # pow2-padded: O(log clusters) jit cache
-        if Cp != Cn:
-            zpad = jnp.zeros((Cp - Cn, C0.shape[1]), C0.dtype)
-            C0 = jnp.concatenate([C0, zpad])
-            B0 = jnp.concatenate([B0, zpad])
-        guard = self.guard
-        res = K.ingest_chain(
-            U, C0, B0, prev_idx, forced_idx, valid,
-            beta=cl.mix_rate, num_centers=Cn, with_stats=guard is not None,
-        )
+            C0 = plane.rows([cl.clusters[c]._row for c in cid_order])
+            B0 = plane.rows([cl.clusters[c]._bcast_row for c in cid_order])
+            Cn = len(cid_order)
+            Cp = 1 << (Cn - 1).bit_length()  # pow2-padded: O(log clusters) jit cache
+            if Cp != Cn:
+                zpad = jnp.zeros((Cp - Cn, C0.shape[1]), C0.dtype)
+                C0 = jnp.concatenate([C0, zpad])
+                B0 = jnp.concatenate([B0, zpad])
+            guard = self.guard
+            res = K.ingest_chain(
+                U, C0, B0, prev_idx, forced_idx, valid,
+                beta=cl.mix_rate, num_centers=Cn, with_stats=guard is not None,
+            )
+            chain.set_metadata(padded=P - S, padded_centers=Cp - Cn)
         # ONE host sync for the whole segment (stats + blended rows: the
         # per-upload center writes re-enter the plane as staged host rows).
         # The guard's post-blend center norms ride the same launch and sync.
         if guard is not None:
             cids_d, blended_d, change_d, gb_d, ga_d, cn_d = res
-            cids_np, change_np, gb_np, ga_np, cnorm_np, blended = jax.device_get(
-                (cids_d[:S], change_d[:S], gb_d[:S], ga_d[:S], cn_d[:S], blended_d[:S])
+            cids_np, change_np, gb_np, ga_np, cnorm_np, blended = fetch(
+                (cids_d[:S], change_d[:S], gb_d[:S], ga_d[:S], cn_d[:S], blended_d[:S]), "chain"
             )
         else:
             cids_d, blended_d, change_d, gb_d, ga_d = res
-            cids_np, change_np, gb_np, ga_np, blended = jax.device_get(
-                (cids_d[:S], change_d[:S], gb_d[:S], ga_d[:S], blended_d[:S])
+            cids_np, change_np, gb_np, ga_np, blended = fetch(
+                (cids_d[:S], change_d[:S], gb_d[:S], ga_d[:S], blended_d[:S]), "chain"
             )
             cnorm_np = None
         blended = np.asarray(blended)
@@ -429,133 +437,135 @@ class EchoPFLServer:
                     if not guard.center_ok(step_cids[jj], float(cnorm_np[jj])):
                         guard_fail = jj
                         break
-            plan = (
-                self._plan_predictor_window(
-                    seg, j0, j1, step_cids, forced_idx,
-                    change_np, gb_np, ga_np, blended, bcast_np, last_vec,
-                )
-                if batch_pred and guard_fail is None
-                else None
-            )
-            for j in range(j0, j1):
-                client_id, params, base_version, n_samples, t = seg[j]
-                self._uploads += 1
-                msgs: list[Downlink] = []
-                cid = step_cids[j]
-                cluster = cl.clusters[cid]
-                if forced_idx[j] < 0:  # partial-finetune members stay put, no move
-                    cl._move(client_id, cid)
-                try:
-                    branch = self.repo.branch(f"cluster/{cid}")
-                except KeyError:
-                    branch = self.repo.branch(f"cluster/{cid}", cluster.center_vec)
+            plan = None
+            if batch_pred and guard_fail is None:
+                with span("ingest/predictor") as sp:
+                    plan = self._plan_predictor_window(
+                        seg, j0, j1, step_cids, forced_idx,
+                        change_np, gb_np, ga_np, blended, bcast_np, last_vec,
+                    )
+                    if tracing.on():
+                        sp.set_metadata(clusters=len(set(step_cids[j0:j1])), launches=plan.launches)
+            with span("ingest/replay"):
+                for j in range(j0, j1):
+                    client_id, params, base_version, n_samples, t = seg[j]
+                    self._uploads += 1
+                    msgs: list[Downlink] = []
+                    cid = step_cids[j]
+                    cluster = cl.clusters[cid]
+                    if forced_idx[j] < 0:  # partial-finetune members stay put, no move
+                        cl._move(client_id, cid)
+                    try:
+                        branch = self.repo.branch(f"cluster/{cid}")
+                    except KeyError:
+                        branch = self.repo.branch(f"cluster/{cid}", cluster.center_vec)
 
-                # staleness bookkeeping — identical to handle_upload
-                base_cluster, base_ver = self.client_versions.get(client_id, (cid, 0))
-                if base_cluster == cid:
-                    staleness = max(0, cluster.version - base_ver)
-                elif base_cluster in cl.clusters:
-                    staleness = max(0, cl.clusters[base_cluster].version - base_ver)
-                else:
-                    staleness = max(0, cluster.version - cluster.last_broadcast_version)
-                self.staleness.record(staleness)
-
-                pred = self._predictor(cid) if self.enable_broadcast else None
-                new_vec = blended[j]
-
-                def merge_fn(head, cluster=cluster, vec=new_vec):
-                    cluster.set_center_vec(vec)
-                    cluster.version += 1
-                    return cluster.center_vec
-
-                branch.push(client_id, merge_fn, f"upload from {client_id} (staleness {staleness})")
-
-                if j == guard_fail:
-                    # the carried center matrix is corrupt from this step
-                    # on: roll back, hand the remainder back for a relaunch
-                    # from the restored live state (same abort discipline as
-                    # a refine that invalidates the speculative launch)
-                    msgs.extend(self._rollback_center(cluster, branch, client_id))
-                    if self._uploads % self.refine_every == 0:
-                        msgs.extend(self._refine())
-                    out.append(msgs)
-                    cl._pending = None
-                    return out, j + 1
-
-                if pred is not None:
-                    change = float(change_np[j])
-                    if plan is None:
-                        b_moved = bcast_np.get(cid)
-                        if b_moved is not None:
-                            # an intra-window broadcast moved this cluster's
-                            # anchor: the precomputed gap is stale. The anchor
-                            # AND the pre-blend center are both host rows we
-                            # already hold (the broadcast step's blended row),
-                            # so the recompute is pure numpy — no device
-                            # round-trip per upload.
-                            gap_before = float(np.abs(last_vec[cid] - b_moved).sum(dtype=np.float32))
-                        else:
-                            gap_before = float(gb_np[j])
-                        label = 1 if change > gap_before else 0
-                        if pred.records:
-                            pred.learn(label)
-                    # with a plan, the fused chain launch already applied the
-                    # SGD steps on host-exact labels; only the record window
-                    # bookkeeping happens per upload
-                    pred.observe(change)
-
-                # unicast payload: host-side numpy views of the blended row we
-                # already synced — bitwise the center the per-event path would
-                # materialize, with zero device dispatches
-                msgs.append(
-                    Downlink(client_id, plane.spec.unflatten_np(new_vec), cluster.version, cid, "unicast")
-                )
-                self.client_versions[client_id] = (cid, cluster.version)
-
-                if pred is not None and cluster.size > 1:
-                    self._decisions += 1
-                    if plan is None:
-                        b_moved = bcast_np.get(cid)
-                        if b_moved is not None:
-                            gap = float(np.abs(new_vec - b_moved).sum(dtype=np.float32))
-                        else:
-                            gap = float(ga_np[j])
-                        want = pred.decide(gap)
+                    # staleness bookkeeping — identical to handle_upload
+                    base_cluster, base_ver = self.client_versions.get(client_id, (cid, 0))
+                    if base_cluster == cid:
+                        staleness = max(0, cluster.version - base_ver)
+                    elif base_cluster in cl.clusters:
+                        staleness = max(0, cl.clusters[base_cluster].version - base_ver)
                     else:
-                        # mirror BroadcastPredictor.decide with the planned
-                        # outcome — counters and the one-suppressed-decision
-                        # activation stay host-exact
-                        pred.decisions += 1
-                        if not pred.active:
-                            pred.active = True
-                            want = False
-                        else:
-                            want = plan.wants[j]
-                        if want:
-                            pred.broadcasts += 1
-                    if want:
-                        self._rnn_broadcasts += 1
-                        msgs.extend(self._broadcast(cluster, exclude={client_id}))
-                        bcast_np[cid] = new_vec  # snapshot_broadcast just copied it
-                last_vec[cid] = new_vec
+                        staleness = max(0, cluster.version - cluster.last_broadcast_version)
+                    self.staleness.record(staleness)
 
-                if j == j1 - 1 and plan is not None:
-                    # write the fused chain's final RNN weights back before a
-                    # refine can inherit them (expansion/merge maintenance)
-                    for wcid, wparams in plan.new_params.items():
-                        self.predictors[wcid].params = wparams
-                if self._uploads % self.refine_every == 0:
-                    msgs.extend(self._refine())
-                    out.append(msgs)
-                    if j + 1 < S and not self._segment_continuation_valid(
-                        seg, j + 1, cid_order, prev_idx, forced_idx
-                    ):
-                        # the refine changed what the speculative launch
-                        # assumed: hand the remainder back for a relaunch
+                    pred = self._predictor(cid) if self.enable_broadcast else None
+                    new_vec = blended[j]
+
+                    def merge_fn(head, cluster=cluster, vec=new_vec):
+                        cluster.set_center_vec(vec)
+                        cluster.version += 1
+                        return cluster.center_vec
+
+                    branch.push(merge_fn)
+
+                    if j == guard_fail:
+                        # the carried center matrix is corrupt from this step
+                        # on: roll back, hand the remainder back for a relaunch
+                        # from the restored live state (same abort discipline as
+                        # a refine that invalidates the speculative launch)
+                        msgs.extend(self._rollback_center(cluster, branch, client_id))
+                        if self._uploads % self.refine_every == 0:
+                            msgs.extend(self._refine())
+                        out.append(msgs)
                         cl._pending = None
                         return out, j + 1
-                else:
-                    out.append(msgs)
+
+                    if pred is not None:
+                        change = float(change_np[j])
+                        if plan is None:
+                            b_moved = bcast_np.get(cid)
+                            if b_moved is not None:
+                                # an intra-window broadcast moved this cluster's
+                                # anchor: the precomputed gap is stale. The anchor
+                                # AND the pre-blend center are both host rows we
+                                # already hold (the broadcast step's blended row),
+                                # so the recompute is pure numpy — no device
+                                # round-trip per upload.
+                                gap_before = float(np.abs(last_vec[cid] - b_moved).sum(dtype=np.float32))
+                            else:
+                                gap_before = float(gb_np[j])
+                            label = 1 if change > gap_before else 0
+                            if pred.records:
+                                pred.learn(label)
+                        # with a plan, the fused chain launch already applied the
+                        # SGD steps on host-exact labels; only the record window
+                        # bookkeeping happens per upload
+                        pred.observe(change)
+
+                    # unicast payload: host-side numpy views of the blended row we
+                    # already synced — bitwise the center the per-event path would
+                    # materialize, with zero device dispatches
+                    msgs.append(
+                        Downlink(client_id, plane.spec.unflatten_np(new_vec), cluster.version, cid, "unicast")
+                    )
+                    self.client_versions[client_id] = (cid, cluster.version)
+
+                    if pred is not None and cluster.size > 1:
+                        self._decisions += 1
+                        if plan is None:
+                            b_moved = bcast_np.get(cid)
+                            if b_moved is not None:
+                                gap = float(np.abs(new_vec - b_moved).sum(dtype=np.float32))
+                            else:
+                                gap = float(ga_np[j])
+                            want = pred.decide(gap)
+                        else:
+                            # mirror BroadcastPredictor.decide with the planned
+                            # outcome — counters and the one-suppressed-decision
+                            # activation stay host-exact
+                            pred.decisions += 1
+                            if not pred.active:
+                                pred.active = True
+                                want = False
+                            else:
+                                want = plan.wants[j]
+                            if want:
+                                pred.broadcasts += 1
+                        if want:
+                            self._rnn_broadcasts += 1
+                            msgs.extend(self._broadcast(cluster, exclude={client_id}))
+                            bcast_np[cid] = new_vec  # snapshot_broadcast just copied it
+                    last_vec[cid] = new_vec
+
+                    if j == j1 - 1 and plan is not None:
+                        # write the fused chain's final RNN weights back before a
+                        # refine can inherit them (expansion/merge maintenance)
+                        for wcid, wparams in plan.new_params.items():
+                            self.predictors[wcid].params = wparams
+                    if self._uploads % self.refine_every == 0:
+                        msgs.extend(self._refine())
+                        out.append(msgs)
+                        if j + 1 < S and not self._segment_continuation_valid(
+                            seg, j + 1, cid_order, prev_idx, forced_idx
+                        ):
+                            # the refine changed what the speculative launch
+                            # assumed: hand the remainder back for a relaunch
+                            cl._pending = None
+                            return out, j + 1
+                    else:
+                        out.append(msgs)
             j0 = j1
         cl._pending = None  # the fused path never uses the assign-time cache
         return out, S
@@ -730,7 +740,7 @@ class EchoPFLServer:
         ]
         if not launch_cids:  # no device work at all this window
             _, wants = resolve({})
-            return _PredictorPlan(wants=wants, new_params={})
+            return _PredictorPlan(wants=wants, new_params={}, launches=0)
 
         # last-upload vector seen by each step BEFORE it runs (evolves at
         # every step of its cluster, chain member or not — mirrors the
@@ -796,7 +806,7 @@ class EchoPFLServer:
 
         used: dict[int, bool] = {}
         if rnn_any:
-            w_host = jax.device_get(wants_dev)  # ONE blocking sync per window
+            w_host = fetch(wants_dev, "predictor")  # ONE blocking sync per window
             for c, wc in w_host.items():
                 for p, st in enumerate(chains[c]):
                     if st["kind"] == "rnn":
@@ -806,15 +816,15 @@ class EchoPFLServer:
             c: finals[c] for c in launch_cids
             if any(st["learn"] for st in chains[c])
         }
-        return _PredictorPlan(wants=wants, new_params=new_params)
+        return _PredictorPlan(wants=wants, new_params=new_params, launches=len(launch_cids))
 
     def _center_norm(self, cluster) -> float:
         """Post-blend center L1 norm for the guard's late check (per-event
         path: one host read per upload — the coalesced path gets the same
         scalar from the fused ``ingest_chain`` stats instead)."""
         if self.clustering.plane is None:
-            return float(np.abs(np.asarray(tree_flat_vector(cluster.center))).sum())
-        return float(np.abs(np.asarray(cluster.center_vec)).sum())
+            return float(np.abs(fetch(tree_flat_vector(cluster.center), "center_norm")).sum())
+        return float(np.abs(fetch(cluster.center_vec, "center_norm")).sum())
 
     def _rollback_center(self, cluster, branch, client_id) -> list[Downlink]:
         """Late detection fired: restore the newest finite last-known-good
@@ -838,7 +848,7 @@ class EchoPFLServer:
                 cluster.center if self.clustering.plane is None else cluster.center_vec
             )
 
-        branch.push(client_id, merge_fn, f"center rollback after poisoned blend from {client_id}")
+        branch.push(merge_fn)
         self.events.append({"kind": "rollback", "cluster": cid, "restored": True})
         return self._broadcast(cluster)
 
@@ -894,9 +904,8 @@ class EchoPFLServer:
             f_pred, f_true, s_soft, seg_ids, num_segments=len(cid_order),
             **self.clustering._kernel_mesh_kwargs(len(entries)),
         )
-        g = np.asarray(g)
+        g, seg_sum = fetch((g, seg_sum), "chi2")
         counts = np.bincount(seg_ids, minlength=len(cid_order))
-        seg_sum = np.asarray(seg_sum)
         self.last_cluster_feedback_mean = {
             cid: float(seg_sum[si] / counts[si])
             for si, cid in enumerate(cid_order)
@@ -940,11 +949,12 @@ class EchoPFLServer:
         f_pred, f_true, s_soft = self._feedback_rows(pairs)
         # probe rows shard over the plane mesh once the flagged-member count
         # crosses mesh_min_rows (the single-device launch stays the default)
-        scores = np.asarray(
+        scores = fetch(
             K.chi2_feedback(
                 f_pred, f_true, s_soft,
                 **self.clustering._kernel_mesh_kwargs(len(pairs)),
-            )
+            ),
+            "chi2",
         ).reshape(len(flagged), len(clusters) - 1)
         moves = 0
         for (m, home, g), row in zip(flagged, scores):
@@ -960,71 +970,77 @@ class EchoPFLServer:
         out: list[Downlink] = []
         if not self.enable_clustering:
             return out
-        self._refine_round += 1
-        if self._refine_round % 5 == 0:  # decay peel counts so later data
-            # drift (Fig. 18) can still split a previously-churned client out
-            self.clustering.peel_counts = {
-                k: v - 1 for k, v in self.clustering.peel_counts.items() if v > 1
-            }
-        # lift head-only mode imposed before this refinement (Sec. 4.3.3:
-        # "only be lifted after the next cluster merging refinement")
-        for cluster in self.clustering.clusters.values():
-            if cluster.partial_finetune and cluster.pf_round < self._refine_round - 1:
-                cluster.partial_finetune.clear()
-        feedback = self._collect_feedback()
-
-        # first try moving poor fits to an existing better-fitting cluster
-        # (probe their feedback against every center); only the leftovers
-        # (fit nowhere) justify spawning a new cluster
-        moved = self._reassign_by_feedback(feedback)
-        if moved:
-            self.events.append({"kind": "reassign", "n": moved})
+        n_events = len(self.events)
+        with span("ingest/refine") as sp:
+            self._refine_round += 1
+            if self._refine_round % 5 == 0:  # decay peel counts so later data
+                # drift (Fig. 18) can still split a previously-churned client out
+                self.clustering.peel_counts = {
+                    k: v - 1 for k, v in self.clustering.peel_counts.items() if v > 1
+                }
+            # lift head-only mode imposed before this refinement (Sec. 4.3.3:
+            # "only be lifted after the next cluster merging refinement")
+            for cluster in self.clustering.clusters.values():
+                if cluster.partial_finetune and cluster.pf_round < self._refine_round - 1:
+                    cluster.partial_finetune.clear()
             feedback = self._collect_feedback()
 
-        # expansion: split poor fits out of each cluster (last uploads are
-        # plane rows in plane mode, pytrees otherwise)
-        uploads = (
-            self.last_uploads if self.clustering.plane is None else self._upload_rows
-        )
-        for cid, fb in list(feedback.items()):
-            if cid not in self.clustering.clusters:
-                continue
-            new_cid = self.clustering.expand(
-                cid, fb, uploads=uploads, refine_round=self._refine_round
-            )
-            if new_cid is not None:
-                parent_pred = self._predictor(cid)
-                new_cluster = self.clustering.clusters[new_cid]
-                change = max(fb.values()) if fb else 0.0
-                self.predictors[new_cid] = predictor_for_expansion(parent_pred, change)
-                self.repo.branch(f"cluster/{new_cid}", new_cluster.center)
-                self.events.append({"kind": "expand", "from": cid, "to": new_cid})
-                for m in new_cluster.members:
-                    self.client_versions[m] = (new_cid, new_cluster.version)
+            # first try moving poor fits to an existing better-fitting cluster
+            # (probe their feedback against every center); only the leftovers
+            # (fit nowhere) justify spawning a new cluster
+            moved = self._reassign_by_feedback(feedback)
+            if moved:
+                self.events.append({"kind": "reassign", "n": moved})
+                feedback = self._collect_feedback()
 
-        # merging: when cluster count exceeds hm * C, fold the nearest pair
-        # when one is genuinely redundant; otherwise dissolve the smallest
-        # cluster (refit its members) — blending two *distinct* centers just
-        # to honor capacity creates the very staleness blob Sec. 4 avoids
-        while self.clustering.should_merge():
-            pair = self.clustering.nearest_pair()
-            if pair is None:
-                if not self._dissolve_smallest():
-                    break
-                continue
-            a, b = pair
-            pred_a, pred_b = self._predictor(a), self._predictor(b)  # before deletion
-            train_fn = self.local_train_fn or (lambda p: p)
-            merged_cid = self.clustering.merge_pair(a, b, train_fn)
-            other = b if merged_cid == a else a
-            pred = predictor_for_merge(pred_a, pred_b)
-            self.predictors[merged_cid] = pred
-            self.predictors.pop(other, None)
-            self.repo.delete(f"cluster/{other}")
-            self.repo.branch(f"cluster/{merged_cid}", self.clustering.clusters[merged_cid].center)
-            self.events.append({"kind": "merge", "into": merged_cid, "from": other})
-            # merged model is immediately broadcast (Sec. 5.2.2)
-            out.extend(self._broadcast(self.clustering.clusters[merged_cid]))
+            # expansion: split poor fits out of each cluster (last uploads are
+            # plane rows in plane mode, pytrees otherwise)
+            uploads = (
+                self.last_uploads if self.clustering.plane is None else self._upload_rows
+            )
+            for cid, fb in list(feedback.items()):
+                if cid not in self.clustering.clusters:
+                    continue
+                new_cid = self.clustering.expand(
+                    cid, fb, uploads=uploads, refine_round=self._refine_round
+                )
+                if new_cid is not None:
+                    parent_pred = self._predictor(cid)
+                    new_cluster = self.clustering.clusters[new_cid]
+                    change = max(fb.values()) if fb else 0.0
+                    self.predictors[new_cid] = predictor_for_expansion(parent_pred, change)
+                    self.repo.branch(f"cluster/{new_cid}", new_cluster.center)
+                    self.events.append({"kind": "expand", "from": cid, "to": new_cid})
+                    for m in new_cluster.members:
+                        self.client_versions[m] = (new_cid, new_cluster.version)
+
+            # merging: when cluster count exceeds hm * C, fold the nearest pair
+            # when one is genuinely redundant; otherwise dissolve the smallest
+            # cluster (refit its members) — blending two *distinct* centers just
+            # to honor capacity creates the very staleness blob Sec. 4 avoids
+            while self.clustering.should_merge():
+                pair = self.clustering.nearest_pair()
+                if pair is None:
+                    if not self._dissolve_smallest():
+                        break
+                    continue
+                a, b = pair
+                pred_a, pred_b = self._predictor(a), self._predictor(b)  # before deletion
+                train_fn = self.local_train_fn or (lambda p: p)
+                merged_cid = self.clustering.merge_pair(a, b, train_fn)
+                other = b if merged_cid == a else a
+                pred = predictor_for_merge(pred_a, pred_b)
+                self.predictors[merged_cid] = pred
+                self.predictors.pop(other, None)
+                self.repo.delete(f"cluster/{other}")
+                self.repo.branch(f"cluster/{merged_cid}", self.clustering.clusters[merged_cid].center)
+                self.events.append({"kind": "merge", "into": merged_cid, "from": other})
+                # merged model is immediately broadcast (Sec. 5.2.2)
+                out.extend(self._broadcast(self.clustering.clusters[merged_cid]))
+            if tracing.on():
+                kinds = Counter(e["kind"] for e in self.events[n_events:])
+                sp.set_metadata(moved=moved, expansions=kinds["expand"], merges=kinds["merge"],
+                                dissolves=kinds["dissolve"])
         return out
 
     def _dissolve_smallest(self) -> bool:
@@ -1046,11 +1062,12 @@ class EchoPFLServer:
             f_pred, f_true, s_soft = self._feedback_rows(
                 [(m, centers[c]) for m in members for c in rest]
             )
-            scores = np.asarray(
+            scores = fetch(
                 K.chi2_feedback(
                     f_pred, f_true, s_soft,
                     **clustering._kernel_mesh_kwargs(len(f_pred)),
-                )
+                ),
+                "chi2",
             ).reshape(len(members), len(rest))
             for m, row in zip(members, scores):
                 best_of[m] = rest[int(np.argmin(row))]
@@ -1063,7 +1080,7 @@ class EchoPFLServer:
                 # replicated
                 U = plane.take([self._upload_rows[m] for m in have], on_mesh="shard" if kw else False)
                 centers = plane.rows([clusters[c]._row for c in rest], on_mesh=bool(kw))
-                D = np.asarray(K.l1_distance_pairwise(U, centers, **kw))
+                D = fetch(K.l1_distance_pairwise(U, centers, **kw), "pairwise_l1")
                 for m, d in zip(have, D):
                     best_of[m] = rest[int(np.argmin(d))]
         elif members:
@@ -1071,7 +1088,7 @@ class EchoPFLServer:
             if with_uploads:
                 centers = jnp.stack([tree_flat_vector(clusters[c].center) for c in rest])
                 U = jnp.stack([tree_flat_vector(self.last_uploads[m]) for m in with_uploads])
-                D = np.asarray(K.l1_distance_pairwise(U, centers))
+                D = fetch(K.l1_distance_pairwise(U, centers), "pairwise_l1")
                 for m, d in zip(with_uploads, D):
                     best_of[m] = rest[int(np.argmin(d))]
         for m in members:

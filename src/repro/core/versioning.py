@@ -8,9 +8,7 @@ conflicts among personalized branches").
 """
 from __future__ import annotations
 
-import dataclasses
 import threading
-import time
 from typing import Any, Callable
 
 PyTree = Any
@@ -51,21 +49,12 @@ class RWLock:
             self._cond.notify_all()
 
 
-@dataclasses.dataclass
-class Commit:
-    version: int
-    author: Any
-    timestamp: float
-    message: str
-
-
 class Branch:
     def __init__(self, name: str, model: PyTree):
         self.name = name
         self._model = model
         self._version = 0
         self._lock = RWLock()
-        self.log: list[Commit] = [Commit(0, "server", time.time(), "branch created")]
 
     def pull(self, have_version: int | None = None) -> tuple[PyTree, int] | None:
         """Fetch (model, version); None if caller is already current."""
@@ -77,13 +66,12 @@ class Branch:
         finally:
             self._lock.release_read()
 
-    def push(self, author, merge_fn: Callable[[PyTree], PyTree], message: str = "") -> int:
+    def push(self, merge_fn: Callable[[PyTree], PyTree]) -> int:
         """Atomically apply ``merge_fn`` (e.g. async aggregation) to the head."""
         self._lock.acquire_write()
         try:
             self._model = merge_fn(self._model)
             self._version += 1
-            self.log.append(Commit(self._version, author, time.time(), message))
             return self._version
         finally:
             self._lock.release_write()
@@ -122,7 +110,7 @@ class ModelRepo:
             src_b = self._branches[src]
             dst_b = self._branches[dst]
             src_model, _ = src_b.pull()
-            dst_b.push("server", lambda head: merge_fn(head, src_model), f"merge {src}")
+            dst_b.push(lambda head: merge_fn(head, src_model))
             self.delete(src)
             return dst_b
 

@@ -188,7 +188,11 @@ def apply_poison(params: Any, kind: str, u: float, cfg: FaultConfig) -> Any:
     multiplies by ``poison_scale_factor``; ``sign`` negates."""
     import jax
 
+    from repro.common.tracing import fetch
+
     leaves, treedef = jax.tree_util.tree_flatten(params)
+    if any(isinstance(x, jax.Array) for x in leaves):
+        leaves = fetch(leaves, "poison")
     out = []
     for x in leaves:
         a = np.array(x)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.pytrees import FlattenSpec, flatten_spec
+from repro.common.tracing import fetch, span
 from repro.core.plane import ParameterPlane
 from repro.fl.tasks import MLP_TASK
 
@@ -227,7 +228,8 @@ class ClientFleet:
     # ------------------------------------------------------------- models
     def set_model(self, cid, params: PyTree) -> None:
         i = self.index[cid]
-        self.plane.write(self._model_row[i], self._vec_of(params))
+        with span("install", rows=1):
+            self.plane.write(self._model_row[i], self._vec_of(params))
         self._has_model[i] = True
         self._model_ver[i] += 1
 
@@ -241,13 +243,13 @@ class ClientFleet:
         latest: dict[int, PyTree] = {}
         for cid, p in zip(cids, params_list):
             latest[self.index[cid]] = p
-        rows, vecs = [], []
-        for i, p in latest.items():
-            rows.append(self._model_row[i])
-            vecs.append(self._vec_of(p))
+        with span("install", rows=len(latest)):
+            with span("install/flatten"):
+                mat = jnp.stack([self._vec_of(p) for p in latest.values()])
+            self.plane.write_rows([self._model_row[i] for i in latest], mat)
+        for i in latest:
             self._has_model[i] = True
             self._model_ver[i] += 1
-        self.plane.write_rows(rows, jnp.stack(vecs))
 
     def model_vec(self, cid) -> jax.Array:
         i = self.index[cid]
@@ -320,7 +322,7 @@ class ClientFleet:
             for c, p in zip(cids, params_list)
         ])
         vecs, losses = self._train(idx, mat, *self._train_specs(cids))
-        vecs_np, losses_np = jax.device_get((vecs, losses))
+        vecs_np, losses_np = fetch((vecs, losses), "fleet_train")
         # the per-client leaves are views over this one base matrix: freeze
         # it so an (unsupported) in-place mutation raises, exactly like the
         # immutable jax-array leaves the loop path hands out
@@ -334,8 +336,9 @@ class ClientFleet:
         this client's model row, writes the new row back, and returns the
         updated params as a pytree plus the device-scalar loss."""
         i = self.index[cid]
-        mat = self.model_vec(cid)[None, :]
-        vecs, losses = self._train(np.asarray([i]), mat, *self._train_specs([cid]))
+        with span("train/launch", rows=1):
+            mat = self.model_vec(cid)[None, :]
+            vecs, losses = self._train(np.asarray([i]), mat, *self._train_specs([cid]))
         vec = vecs[0]
         self.plane.write(self._model_row[i], vec)
         self._has_model[i] = True
@@ -360,13 +363,14 @@ class ClientFleet:
         # incrementally); the batch's rows are gathered from it inside the
         # launch — an eager scattered-row gather per window is the slow
         # path on CPU
-        bank = self.plane.rows(tuple(self._model_row))
-        vecs, losses = self._train(idx, None, *self._train_specs(cids), bank=bank)
+        with span("train/launch", rows=len(cids)):
+            bank = self.plane.rows(tuple(self._model_row))
+            vecs, losses = self._train(idx, None, *self._train_specs(cids), bank=bank)
         self.plane.write_rows([self._model_row[i] for i in idx], vecs)
         for i in idx:
             self._has_model[i] = True
             self._model_ver[i] += 1
-        vecs_np, losses_np = jax.device_get((vecs, losses))
+        vecs_np, losses_np = fetch((vecs, losses), "fleet_train")
         vecs_np = np.asarray(vecs_np)
         vecs_np.flags.writeable = False  # leaves are views: freeze like train_cohort
         out = [self.to_pytree_np(v) for v in vecs_np], losses_np
@@ -405,9 +409,7 @@ class ClientFleet:
         # so it can share the launch with the client-sharded data tensors)
         mat = plane.rows(tuple(self._eval_row), on_mesh=self.mesh is not None)
         self.launches += 1
-        accs = np.asarray(
-            _eval_launch(mat, self._test_data, spec=self.spec, task=self.task)
-        )
+        accs = fetch(_eval_launch(mat, self._test_data, spec=self.spec, task=self.task), "eval")
         if zero.any():
             accs = np.where(zero, 0.0, accs)
         return accs
@@ -447,5 +449,5 @@ class ClientFleet:
             self._rep(bank), self._rep(sel), self._train_data, self._rep(gather),
             spec=self.spec, num_classes=self.num_classes, task=self.task,
         )
-        f_pred, s_soft = jax.device_get((f_pred[:M], s_soft[:M]))
+        f_pred, s_soft = fetch((f_pred[:M], s_soft[:M]), "feedback")
         return np.asarray(f_pred), self.f_true[idx], np.asarray(s_soft)

@@ -142,11 +142,16 @@ def resolve_guard(spec: Any = None) -> GuardConfig | None:
 
 def _leaves(tree: Any) -> list[np.ndarray]:
     """Host-numpy leaves of a pytree without importing jax here: the
-    payloads the guard sees are already host numpy views on the
-    coalesced path; the per-event path pays one ``np.asarray`` sync."""
+    payloads the guard sees are mostly host numpy views already; a
+    device payload (a one-client training launch) pays one read."""
     import jax
 
-    return [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
+    from repro.common.tracing import fetch
+
+    leaves = jax.tree_util.tree_leaves(tree)
+    if any(isinstance(x, jax.Array) for x in leaves):
+        leaves = fetch(leaves, "guard")
+    return [np.asarray(x) for x in leaves]
 
 
 def _robust_bound(hist: deque, k: float, rel_floor: float) -> float:

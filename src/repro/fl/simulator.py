@@ -23,6 +23,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro.common import tracing
+from repro.common.tracing import span
 from repro.core.client import SimClient
 from repro.fl.faults import FaultInjector, resolve_faults
 from repro.fl.network import NetworkModel
@@ -704,6 +706,7 @@ class Simulator:
 
         next_eval = self.eval_interval
         uploads = 0
+        superstep = 0
         t = 0.0
         while events:
             if self._faults is not None and self._faults.restart_due(uploads):
@@ -726,37 +729,46 @@ class Simulator:
                     push(t + strat.tick_interval, "tick", None)
                 continue
 
-            # collect the window and bucket by kind (time order within each)
-            buckets: dict[str, list] = {"downlink": [], "upload_start": [], "upload_done": []}
-            s0 = stash(t0, kind, payload)
-            buckets[kind].append((t0, payload, s0))
-            limit = t0 + window
-            cap = max_uploads - uploads if max_uploads else None
-            # the cap counts ACCEPTED ingests only: dup-fenced, guard-rejected
-            # and guard-evicted arrivals never reach the server (a pre-drawn
-            # float compute time marks an arrival that will actually ingest)
-            ud_seen = 1 if kind == "upload_done" and isinstance(s0, float) else 0
-            while events and (cap is None or ud_seen < cap):
-                tn, _, kn, pn = events[0]
-                if kn == "tick" or tn >= limit or tn >= next_eval or tn > max_time:
-                    break
-                heapq.heappop(events)
-                sn = stash(tn, kn, pn)
-                buckets[kn].append((tn, pn, sn))
-                t = tn
-                ud_seen += kn == "upload_done" and isinstance(sn, float)
-            for kn, group in buckets.items():
-                if group:
-                    self.coalesced_groups.setdefault(kn, []).append(len(group))
+            superstep += 1
+            with span("superstep", superstep=superstep) as step:
+                # collect the window and bucket by kind (time order within each)
+                with span("collect"):
+                    buckets: dict[str, list] = {"downlink": [], "upload_start": [], "upload_done": []}
+                    s0 = stash(t0, kind, payload)
+                    buckets[kind].append((t0, payload, s0))
+                    limit = t0 + window
+                    cap = max_uploads - uploads if max_uploads else None
+                    # the cap counts ACCEPTED ingests only: dup-fenced, guard-rejected
+                    # and guard-evicted arrivals never reach the server (a pre-drawn
+                    # float compute time marks an arrival that will actually ingest)
+                    ud_seen = 1 if kind == "upload_done" and isinstance(s0, float) else 0
+                    while events and (cap is None or ud_seen < cap):
+                        tn, _, kn, pn = events[0]
+                        if kn == "tick" or tn >= limit or tn >= next_eval or tn > max_time:
+                            break
+                        heapq.heappop(events)
+                        sn = stash(tn, kn, pn)
+                        buckets[kn].append((tn, pn, sn))
+                        t = tn
+                        ud_seen += kn == "upload_done" and isinstance(sn, float)
+                    for kn, group in buckets.items():
+                        if group:
+                            self.coalesced_groups.setdefault(kn, []).append(len(group))
+                if tracing.on():
+                    step.set_metadata(
+                        downlinks=sum(len(p) if isinstance(p, list) else 1 for _, p, _ in buckets["downlink"]),
+                        starts=len(buckets["upload_start"]),
+                        uploads=len(buckets["upload_done"]),
+                    )
 
-            if buckets["downlink"]:
-                self._coalesced_downlinks(buckets["downlink"])
-            if buckets["upload_start"]:
-                self._coalesced_upload_starts(buckets["upload_start"], push)
-            if buckets["upload_done"]:
-                uploads += self._coalesced_upload_dones(buckets["upload_done"], push)
-                if max_uploads and uploads >= max_uploads:
-                    break
+                if buckets["downlink"]:
+                    self._coalesced_downlinks(buckets["downlink"])
+                if buckets["upload_start"]:
+                    self._coalesced_upload_starts(buckets["upload_start"], push)
+                if buckets["upload_done"]:
+                    uploads += self._coalesced_upload_dones(buckets["upload_done"], push)
+                    if max_uploads and uploads >= max_uploads:
+                        break
 
         extra = strat.stats() if hasattr(strat, "stats") else {}
         extra["uploads"] = uploads
@@ -779,36 +791,39 @@ class Simulator:
         trained: dict[Any, Any] = {}
         encoded: dict[Any, Any] = {}
         if self._fleet is not None and len(ready) > 1:
-            if self._codec is not None:
-                # the window's whole cohort compresses as ONE codec launch,
-                # fed the training launch's device matrix directly (no
-                # per-client re-flatten round trip)
-                outs, _, vecs = self._fleet.train_rows(ready, with_vecs=True)
-                recs, _ = self._codec.encode_rows(ready, vecs)
-                encoded = dict(zip(ready, recs))
-            else:
-                outs, _ = self._fleet.train_rows(ready)
+            with span("train", rows=len(ready)):
+                if self._codec is not None:
+                    # the window's whole cohort compresses as ONE codec launch,
+                    # fed the training launch's device matrix directly (no
+                    # per-client re-flatten round trip)
+                    outs, _, vecs = self._fleet.train_rows(ready, with_vecs=True)
+                    recs, _ = self._codec.encode_rows(ready, vecs)
+                    encoded = dict(zip(ready, recs))
+                else:
+                    outs, _ = self._fleet.train_rows(ready)
             trained = dict(zip(ready, outs))
-        for ti, cid, resume in group:
-            if resume == math.inf:  # fatal crash: the device never returns
-                self._retire_client(cid, "death")
-                continue
-            if resume is not None:  # device was offline: resumes when back
-                push(resume, "upload_start", cid)
-                continue
-            c = self.clients[cid]
-            if cid in trained:
-                new_params = trained[cid]
-            elif self._fleet is not None:
-                new_params, _ = self._fleet.train_client(cid)
-            else:
-                new_params, _ = c.local_train()
-            c.model = new_params
-            if cid in encoded:
-                up_params, nbytes, raw = encoded[cid], self._codec.nbytes, model_bytes(new_params)
-            else:
-                up_params, nbytes, raw = self._encode_upload(cid, new_params)
-            self._send_upload(push, ti, cid, up_params, nbytes, raw, c.base_version)
+        for cid in ready:
+            if cid not in trained:
+                with span("train", rows=1):
+                    if self._fleet is not None:
+                        trained[cid], _ = self._fleet.train_client(cid)
+                    else:
+                        trained[cid], _ = self.clients[cid].local_train()
+        with span("bill"):
+            for ti, cid, resume in group:
+                if resume == math.inf:  # fatal crash: the device never returns
+                    self._retire_client(cid, "death")
+                    continue
+                if resume is not None:  # device was offline: resumes when back
+                    push(resume, "upload_start", cid)
+                    continue
+                c = self.clients[cid]
+                new_params = c.model = trained[cid]
+                if cid in encoded:
+                    up_params, nbytes, raw = encoded[cid], self._codec.nbytes, model_bytes(new_params)
+                else:
+                    up_params, nbytes, raw = self._encode_upload(cid, new_params)
+                self._send_upload(push, ti, cid, up_params, nbytes, raw, c.base_version)
 
     def _coalesced_upload_dones(self, group, push) -> int:
         """One batched server ingest for a window of arrivals; downlinks
@@ -824,56 +839,58 @@ class Simulator:
             (cid, params, bv, self.clients[cid].data.n, ti)
             for ti, (cid, params, bv, _useq), _ in live
         ]
+        downlinks_per = []
         if batch:
-            if len(batch) > 1 and hasattr(strat, "handle_uploads"):
-                downlinks_per = strat.handle_uploads(batch)
-            else:
-                downlinks_per = [strat.handle_upload(*b) for b in batch]
-        else:
-            downlinks_per = []
-        dls_iter = iter(downlinks_per)
-        for ti, (cid, _params, _bv, _useq), sn in group:
-            if sn == "dup" or sn == "evicted":
-                continue
-            if isinstance(sn, tuple):  # guard-rejected: reschedule only
-                push(ti + sn[1], "upload_start", cid)
-                continue
-            next_compute = sn
-            dls = next(dls_iter)
-            if self._faults is not None:
-                # fault mode bills and ships each downlink individually so
-                # sequence numbers and injected reorder delays land exactly
-                # as the per-event loop's (byte totals and event counts are
-                # identical to the bulk billing either way)
+            with span("ingest", uploads=len(batch)):
+                if len(batch) > 1 and hasattr(strat, "handle_uploads"):
+                    downlinks_per = strat.handle_uploads(batch)
+                else:
+                    with span("ingest/single", reason="one" if len(batch) == 1 else "unbatched"):
+                        downlinks_per = [strat.handle_upload(*b) for b in batch]
+        with span("bill"):
+            dls_iter = iter(downlinks_per)
+            for ti, (cid, _params, _bv, _useq), sn in group:
+                if sn == "dup" or sn == "evicted":
+                    continue
+                if isinstance(sn, tuple):  # guard-rejected: reschedule only
+                    push(ti + sn[1], "upload_start", cid)
+                    continue
+                next_compute = sn
+                dls = next(dls_iter)
+                if self._faults is not None:
+                    # fault mode bills and ships each downlink individually so
+                    # sequence numbers and injected reorder delays land exactly
+                    # as the per-event loop's (byte totals and event counts are
+                    # identical to the bulk billing either way)
+                    for dl in dls:
+                        dur = self.net.download(model_bytes(dl.params), ti)
+                        self._push_downlink(push, ti, dl, dur)
+                    push(ti + next_compute, "upload_start", cid)
+                    continue
+                # every downlink of one ingest carries a whole model (unicast
+                # and echo broadcast alike), so the fan-out shares one wire
+                # size and one transfer duration: bill it in one call and ship
+                # it as ONE batch event instead of len(fan-out) heap entries —
+                # the per-downlink Python (push/pop/billing) is what dominates
+                # the echo at fleet scale
+                run: list = []
+                run_obj, run_nb = None, 0
                 for dl in dls:
-                    dur = self.net.download(model_bytes(dl.params), ti)
-                    self._push_downlink(push, ti, dl, dur)
+                    if run and dl.params is not run_obj:  # a broadcast fans one object
+                        nb = model_bytes(dl.params)
+                        if nb != run_nb:
+                            dur = self.net.download_bulk(run_nb, len(run), ti)
+                            push(ti + dur, "downlink", run)
+                            run = []
+                        run_obj, run_nb = dl.params, nb
+                    elif not run:
+                        run_obj, run_nb = dl.params, model_bytes(dl.params)
+                    run.append(dl)
+                if run:
+                    dur = self.net.download_bulk(run_nb, len(run), ti)
+                    push(ti + dur, "downlink", run)
+                # next local round: duration pre-drawn at collection time
                 push(ti + next_compute, "upload_start", cid)
-                continue
-            # every downlink of one ingest carries a whole model (unicast
-            # and echo broadcast alike), so the fan-out shares one wire
-            # size and one transfer duration: bill it in one call and ship
-            # it as ONE batch event instead of len(fan-out) heap entries —
-            # the per-downlink Python (push/pop/billing) is what dominates
-            # the echo at fleet scale
-            run: list = []
-            run_obj, run_nb = None, 0
-            for dl in dls:
-                if run and dl.params is not run_obj:  # a broadcast fans one object
-                    nb = model_bytes(dl.params)
-                    if nb != run_nb:
-                        dur = self.net.download_bulk(run_nb, len(run), ti)
-                        push(ti + dur, "downlink", run)
-                        run = []
-                    run_obj, run_nb = dl.params, nb
-                elif not run:
-                    run_obj, run_nb = dl.params, model_bytes(dl.params)
-                run.append(dl)
-            if run:
-                dur = self.net.download_bulk(run_nb, len(run), ti)
-                push(ti + dur, "downlink", run)
-            # next local round: duration pre-drawn at collection time
-            push(ti + next_compute, "upload_start", cid)
         return len(batch)
 
     def _coalesced_downlinks(self, group) -> None:

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.pytrees import flatten_spec
+from repro.common.tracing import fetch, span
 from repro.core.plane import ParameterPlane
 from repro.optim.compression import (
     Int8Payload,
@@ -290,28 +291,29 @@ class UplinkCodec:
                 raise ValueError(f"client {c}'s uplink codec rows were released")
             if not self._seeded[i]:
                 raise ValueError(f"client {c} has no uplink anchor seeded")
-        B = len(idx)
-        P = _pow2(B)
-        sel = np.asarray(idx + [idx[0]] * (P - B), np.int32)
-        mat = jnp.asarray(mat, self.plane.dtype)
-        if P != B:
-            mat = jnp.concatenate([mat, jnp.broadcast_to(mat[:1], (P - B, mat.shape[1]))])
-        bank_a = self.plane.rows(self._bank_rows(self._anchor_row))
-        self.launches += 1
-        if self.mode == "topk":
-            bank_r = self.plane.rows(self._bank_rows(self._resid_row))
-            rec, new_r = _encode_topk(bank_a, bank_r, sel, mat, k=self.k)
-            rec = rec[:B]
-            rows = [self._resid_row[i] for i in idx] + [self._anchor_row[i] for i in idx]
-            self.plane.write_rows(rows, jnp.concatenate([new_r[:B], rec], axis=0))
-        else:
-            rec = _encode_int8(bank_a, sel, mat, chunk=self.chunk)[:B]
-            self.plane.write_rows([self._anchor_row[i] for i in idx], rec)
-        rec_np = np.asarray(jax.device_get(rec))
-        # the reconstructed pytrees hand out views over this matrix: freeze
-        # it so an (unsupported) in-place mutation raises, like fleet outputs
-        rec_np.flags.writeable = False
-        return rec_np
+        with span("codec", rows=len(idx)):
+            B = len(idx)
+            P = _pow2(B)
+            sel = np.asarray(idx + [idx[0]] * (P - B), np.int32)
+            mat = jnp.asarray(mat, self.plane.dtype)
+            if P != B:
+                mat = jnp.concatenate([mat, jnp.broadcast_to(mat[:1], (P - B, mat.shape[1]))])
+            bank_a = self.plane.rows(self._bank_rows(self._anchor_row))
+            self.launches += 1
+            if self.mode == "topk":
+                bank_r = self.plane.rows(self._bank_rows(self._resid_row))
+                rec, new_r = _encode_topk(bank_a, bank_r, sel, mat, k=self.k)
+                rec = rec[:B]
+                rows = [self._resid_row[i] for i in idx] + [self._anchor_row[i] for i in idx]
+                self.plane.write_rows(rows, jnp.concatenate([new_r[:B], rec], axis=0))
+            else:
+                rec = _encode_int8(bank_a, sel, mat, chunk=self.chunk)[:B]
+                self.plane.write_rows([self._anchor_row[i] for i in idx], rec)
+            rec_np = np.asarray(fetch(rec, "codec"))
+            # the reconstructed pytrees hand out views over this matrix: freeze
+            # it so an (unsupported) in-place mutation raises, like fleet outputs
+            rec_np.flags.writeable = False
+            return rec_np
 
     def encode_rows(self, cids: Sequence[Any], mat) -> tuple[list[PyTree], int]:
         """Cohort form: reconstructed per-client pytrees (numpy views over

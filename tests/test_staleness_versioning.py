@@ -57,7 +57,7 @@ def test_branch_push_pull_roundtrip():
     repo = ModelRepo()
     b = repo.branch("cluster/0", {"w": 0.0})
     assert b.pull() == ({"w": 0.0}, 0)
-    v = b.push("client1", lambda head: {"w": head["w"] + 1.0}, "inc")
+    v = b.push(lambda head: {"w": head["w"] + 1.0})
     assert v == 1
     assert b.pull() == ({"w": 1.0}, 1)
     assert b.pull(have_version=1) is None   # already current
@@ -79,7 +79,7 @@ def test_concurrent_pushes_lose_nothing():
 
     def worker():
         for _ in range(per):
-            b.push("t", lambda head: {"w": head["w"] + 1})
+            b.push(lambda head: {"w": head["w"] + 1})
 
     threads = [threading.Thread(target=worker) for _ in range(n)]
     for t in threads:
@@ -105,7 +105,7 @@ def test_concurrent_reads_during_writes():
     rt = threading.Thread(target=reader)
     rt.start()
     for _ in range(200):
-        b.push("w", lambda head: {"w": head["w"] + 1})
+        b.push(lambda head: {"w": head["w"] + 1})
     stop.set()
     rt.join()
     assert not errors, f"torn reads: {errors[:3]}"
