@@ -46,6 +46,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.common.pytrees import FlattenSpec, flatten_spec
 from repro.common.tracing import fetch, span
@@ -90,6 +91,40 @@ def _train_launch_bank(bank, sel, train, gather, lr, epochs, head, *,
 @functools.partial(jax.jit, static_argnames=("spec", "task"))
 def _eval_launch(mat, test, *, spec: FlattenSpec, task):
     return task.fleet_evaluate(jax.vmap(spec._unflatten)(mat), test)
+
+
+# A downlink batch fans a few distinct payloads out over many rows. Its
+# (rows, dim) matrix is gathered in one launch from a bank of the distinct
+# payloads: the host payloads' rows, then the device payloads' vectors. Each
+# part holds at least _BANK_MIN slots (a power of two above), so one program
+# serves every batch of a row count that holds a handful of each kind.
+_BANK_MIN = 8
+
+_ROW = lax.GatherDimensionNumbers(offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+
+
+@jax.jit
+def _install_rows(host, devs, sel):
+    # plain lax: jnp's indexing traces helper jits for every new shape
+    bank = lax.concatenate(
+        [host, *(lax.expand_dims(lax.convert_element_type(v, host.dtype), (0,)) for v in devs)], 0
+    )
+    return lax.gather(bank, sel[:, None], _ROW, (1, bank.shape[1]),
+                      mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _bank(n: int) -> int:
+    return max(_BANK_MIN, _pow2(n))
+
+
+def _host_leaves(params: PyTree) -> list | None:
+    """The leaves of a payload held in host memory (every leaf a NumPy
+    array, like the unicasts the server builds with ``unflatten_np``), else
+    None."""
+    leaves = jax.tree_util.tree_leaves(params)
+    if leaves and all(isinstance(x, np.ndarray) for x in leaves):
+        return leaves
+    return None
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "num_classes", "task"))
@@ -228,24 +263,48 @@ class ClientFleet:
     # ------------------------------------------------------------- models
     def set_model(self, cid, params: PyTree) -> None:
         i = self.index[cid]
-        with span("install", rows=1):
+        with span("install", rows=1, distinct=1):
             self.plane.write(self._model_row[i], self._vec_of(params))
         self._has_model[i] = True
         self._model_ver[i] += 1
 
     def set_models(self, cids: Sequence[Any], params_list: Sequence[PyTree]) -> None:
-        """Install a batch of downlinked models in one staged write: a
-        broadcast's fan-out (N downlinks of the SAME center object landing
-        at the same virtual time) costs one cached flatten and one
-        ``write_rows`` staging entry instead of N row stagings. Duplicate
-        clients keep the LAST entry, matching sequential ``set_model``
-        overwrite order."""
+        """Install a batch of downlinked models in one staged write.
+        Duplicate clients keep the LAST entry, matching sequential
+        ``set_model`` overwrite order.
+
+        A batch carries few distinct payload objects (a broadcast fans one
+        center out to every member), so the ``(rows, dim)`` matrix is built
+        from the distinct payloads and a row -> payload index: host payloads
+        flatten in NumPy into one block (one transfer), device payloads go
+        through the identity-cached ``_vec_of``, and one launch gathers the
+        rows — bitwise the rows sequential ``set_model`` calls would
+        stage."""
         latest: dict[int, PyTree] = {}
         for cid, p in zip(cids, params_list):
             latest[self.index[cid]] = p
-        with span("install", rows=len(latest)):
+        with span("install", rows=len(latest)) as sp:
             with span("install/flatten"):
-                mat = jnp.stack([self._vec_of(p) for p in latest.values()])
+                slot: dict[int, int] = {}  # id(payload) -> host slot, or ~device slot
+                host: list = []
+                devs: list = []
+                for p in latest.values():
+                    if id(p) not in slot:
+                        leaves = _host_leaves(p)
+                        if leaves is None:
+                            slot[id(p)] = ~len(devs)
+                            devs.append(self._vec_of(p))
+                        else:
+                            slot[id(p)] = len(host)
+                            host.append(np.concatenate([np.ravel(x) for x in leaves]))
+                sp.set_metadata(distinct=len(slot))
+                block = np.zeros((_bank(len(host)), self.spec.dim), self.plane.dtype)
+                for row, vec in zip(block, host):
+                    row[:] = vec
+                sel = np.array([slot[id(p)] for p in latest.values()], np.int32)
+                sel = np.where(sel >= 0, sel, len(block) + ~sel).astype(np.int32)
+                devs += devs[:1] * (_bank(len(devs)) - len(devs))
+                mat = _install_rows(block, tuple(devs), sel)
             self.plane.write_rows([self._model_row[i] for i in latest], mat)
         for i in latest:
             self._has_model[i] = True

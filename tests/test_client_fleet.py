@@ -220,6 +220,104 @@ class TestFleetEngine:
             np.asarray(fleet.spec.flatten(other)), rtol=1e-7,
         )
 
+    @staticmethod
+    def _payloads(rng, params, spec):
+        """Fresh payload objects of every kind a downlink carries: device
+        pytrees (broadcast centers), 1-D device vectors, host pytrees of
+        NumPy views (unicasts) and a host pytree of float64 leaves."""
+        def vec():
+            return rng.normal(size=spec.dim).astype(np.float32)
+
+        return {
+            "device": jax.tree_util.tree_map(lambda x: x + jnp.float32(rng.normal()), params),
+            "vector": jnp.asarray(vec()),
+            "host": spec.unflatten_np(vec()),
+            "host64": jax.tree_util.tree_map(lambda x: x.astype(np.float64), spec.unflatten_np(vec())),
+        }
+
+    @pytest.mark.parametrize("case", ["mixed", "one_device", "one_host", "all_distinct"])
+    def test_set_models_stages_rows_bitwise_like_sequential_installs(self, rng, params, case):
+        """Staged and flushed rows of one batched install are bitwise what
+        sequential ``set_model`` calls stage, whatever the batch's mix of
+        host and device payloads, duplicates and fan-out."""
+        clients = _ragged_clients(rng, sizes=(6,) * 72)
+        ids = [c.client_id for c in clients]
+        batched = ClientFleet(clients, params)
+        seq = ClientFleet(clients, params)
+        p = self._payloads(rng, params, batched.spec)
+        if case == "mixed":
+            # the center fanned to most rows, unicasts, a vector, and
+            # client 0 twice: the last write wins
+            order = [p["device"]] * 50 + [p["host"]] * 6 + [p["vector"]] * 4 + [p["host64"]] * 4
+            pairs = list(zip(ids[:64], order)) + [(ids[0], p["host"]), (ids[70], p["vector"])]
+        elif case in ("one_device", "one_host"):
+            pairs = [(cid, p[case[4:]]) for cid in ids]
+        else:
+            pairs = [(cid, self._payloads(rng, params, batched.spec)[k])
+                     for cid, k in zip(ids, ["device", "vector", "host", "host64"] * 18)]
+        batched.set_models([c for c, _ in pairs], [q for _, q in pairs])
+        for c, q in pairs:
+            seq.set_model(c, q)
+        installed = sorted({c for c, _ in pairs})
+
+        def rows(fleet):
+            return [np.asarray(fleet.model_vec(cid)) for cid in installed]
+
+        staged = rows(batched), rows(seq)
+        batched.plane.flush()
+        seq.plane.flush()
+        for got, want in (staged, (rows(batched), rows(seq))):
+            for cid, a, b in zip(installed, got, want):
+                assert np.array_equal(a, b), cid
+
+    def test_set_models_device_work_does_not_grow_with_rows(self, rng, params, monkeypatch):
+        """An install of 200 rows over three payloads costs the device what
+        one of 8 rows does: one flatten per distinct device payload and one
+        launch, never a dispatch per row."""
+        import repro.fl.fleet as fleet_mod
+
+        clients = _ragged_clients(rng, sizes=(6,) * 200)
+        ids = [c.client_id for c in clients]
+        fleet = ClientFleet(clients, params)
+        counts: dict = {}
+
+        def counting(name, fn):
+            def call(*a, **k):
+                counts[name] = counts.get(name, 0) + 1
+                return fn(*a, **k)
+            return call
+
+        monkeypatch.setattr(fleet, "_vec_of", counting("vec_of", fleet._vec_of))
+        monkeypatch.setattr(fleet.spec, "flatten", counting("flatten", fleet.spec.flatten))
+        monkeypatch.setattr(fleet_mod, "_install_rows",
+                            counting("launch", getattr(fleet_mod, "_install_rows", None)), raising=False)
+
+        def install(n):
+            counts.clear()
+            p = self._payloads(rng, params, fleet.spec)
+            three = [p["device"], p["vector"], p["host"]]
+            fleet.set_models(ids[:n], [three[i % 3] for i in range(n)])
+            return dict(counts)
+
+        small, large = install(8), install(200)
+        assert small == large == {"vec_of": 2, "flatten": 1, "launch": 1}
+
+    def test_set_models_programs_key_on_padded_distinct_counts(self, rng, params):
+        """One, three or eight distinct host payloads over the same rows run
+        one program: the install is keyed by the row count and the distinct
+        counts padded, not by the exact distinct counts."""
+        import repro.fl.fleet as fleet_mod
+
+        clients = _ragged_clients(rng, sizes=(6,) * 16)
+        ids = [c.client_id for c in clients]
+        fleet = ClientFleet(clients, params)
+        sizes = []
+        for distinct in (1, 3, 8):
+            hosts = [self._payloads(rng, params, fleet.spec)["host"] for _ in range(distinct)]
+            fleet.set_models(ids, [hosts[i % distinct] for i in range(len(ids))])
+            sizes.append(fleet_mod._install_rows._cache_size())
+        assert sizes[0] == sizes[1] == sizes[2]
+
     def test_dataset_replacement_is_picked_up(self, rng, params):
         """Distribution drift (Fig. 18): replacing a SimClient's dataset
         mid-run must be reflected by the next fleet launch, like the loop

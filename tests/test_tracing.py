@@ -187,3 +187,9 @@ def test_span_tree(runs):
     assert all(c["steps"] >= 2 and c["padded"] >= 0 and c["centers"] >= 1 for c in chains)
     refines = [s[4] for s in spans if s[1] == "ingest/refine"]
     assert refines and all({"moved", "expansions", "merges", "dissolves"} <= set(r) for r in refines)
+
+
+def test_install_span_counts_distinct_payloads(runs):
+    installs = [s[4] for s in runs["spans"] if s[1] == "install"]
+    assert installs and all(1 <= s["distinct"] <= s["rows"] for s in installs)
+    assert any(s["distinct"] < s["rows"] for s in installs)  # a broadcast fans one center out
