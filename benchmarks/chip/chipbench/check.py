@@ -4,8 +4,9 @@ Compared, after the window has closed:
 
 * ``train_gap`` — fleet training. A sample, drawn from the seed, of the
   uploads the window's ``train_rows`` launches produced. Each is retrained
-  by :func:`reference.local_train` from the model the client held (its last
-  downlink) on the client's data as the benchmark's generator made it. The
+  by the client model's ``train_reference`` (the plain reference its
+  configuration names) from the model the client held (its last downlink)
+  on the client's data as the client model drew it. The
   number is the largest ``|update - reference update| / |reference update|``
   (L2 over the row), where an update is the trained row minus the row
   trained from.
@@ -40,12 +41,13 @@ def _rel_max(got, want) -> float:
 
 def readings(cell, seed, rec, data, sim, *, control: bool = False) -> dict:
     """``{number: (value, answers compared, answers above the limit)}``."""
-    cfg = cell.config
-    limits = cfg["limits"]
-    widths = counts.widths(cfg)
-    out: dict = {}
+    return {**train_readings(cell, seed, rec, data, control=control),
+            **ingest_readings(cell, rec, control=control), **ledger_readings(cell, rec, sim)}
 
-    # fleet training
+
+def train_readings(cell, seed, rec, data, *, control: bool = False) -> dict:
+    """``train_gap``: fleet training against the client model's reference."""
+    cfg, model = cell.config, cell.model
     rng = np.random.default_rng([seed, 1])
     pool = rec.samples.train
     pick = rng.choice(len(pool), size=min(TRAIN_SAMPLE, len(pool)), replace=False) if pool else []
@@ -53,16 +55,20 @@ def readings(cell, seed, rec, data, sim, *, control: bool = False) -> dict:
     for i in sorted(pick):
         cid, base, trained, head, lr, epochs = pool[i]
         b = flat(base).astype(np.float64)
-        d = data[cid]
         kw = dict(epochs=epochs, lr=lr, head_only=head)
-        want = reference.local_train(b, d.x_train, d.y_train, widths, **kw)
-        got = (reference.local_train(b, d.x_train, d.y_train, widths, cast=reference.bf16, **kw)
+        want = model.train_reference(cfg, b, data[cid], **kw)
+        got = (model.train_reference(cfg, b, data[cid], cast=reference.bf16, **kw)
                if control else flat(trained).astype(np.float64))
         du, dr = got - b, want - b
         gaps.append(float(np.linalg.norm(du - dr) / max(np.linalg.norm(dr), 1e-30)))
-    out["train_gap"] = _summary(gaps, limits["train_gap"])
+    return {"train_gap": _summary(gaps, cfg["limits"]["train_gap"])}
 
-    # server ingest
+
+def ingest_readings(cell, rec, *, control: bool = False) -> dict:
+    """``ingest_gap`` and ``assign_miss``: server ingest against the
+    sequential reference."""
+    cfg = cell.config
+    limits = cfg["limits"]
     gaps, miss, n_up = [], 0, 0
     beta, margin = cfg["mix_rate"], cfg["switch_margin"]
     for pre, post, uploads in rec.samples.ingest:
@@ -81,16 +87,17 @@ def readings(cell, seed, rec, data, sim, *, control: bool = False) -> dict:
         miss += sum(final_got[c] != final_ref[c] for c in final_ref)
         n_up += len(ups)
         gaps.extend(_rel_max(got_c[c], ref_c[c]) for c in set(ref_chosen))
-    out["ingest_gap"] = _summary(gaps, limits["ingest_gap"])
-    out["assign_miss"] = (float(miss), n_up, int(miss > limits["assign_miss"]) * miss)
+    return {"ingest_gap": _summary(gaps, limits["ingest_gap"]),
+            "assign_miss": (float(miss), n_up, int(miss > limits["assign_miss"]) * miss)}
 
-    # byte ledger
+
+def ledger_readings(cell, rec, sim) -> dict:
+    """``ledger_bytes_off``: the byte ledger against the client model's row."""
     net = sim.net
-    row = counts.F32 * counts.row_floats(cfg)
+    row = counts.F32 * cell.model.row_floats(cell.config)
     off = (abs(net.up_bytes - net.up_events * row) + abs(net.up_raw_bytes - net.up_events * row)
            + abs(net.down_bytes - net.down_events * row) + abs(net.up_events - rec.trained_total) * row)
-    out["ledger_bytes_off"] = (float(off), 1, int(off > limits["ledger_bytes_off"]))
-    return out
+    return {"ledger_bytes_off": (float(off), 1, int(off > cell.config["limits"]["ledger_bytes_off"]))}
 
 
 def _summary(gaps, limit):

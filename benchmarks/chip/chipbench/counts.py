@@ -2,46 +2,12 @@
 
 These are the yardstick's own counts: a metric divides them by a measured
 time, so they count what the algorithm needs, not what a given program
-happens to move.
+happens to move. What a client's model requires (its row, its training
+FLOPs) is counted by the configuration's client model (``models/``).
 """
 from __future__ import annotations
 
 F32 = 4
-
-
-def widths(config: dict) -> list[int]:
-    return [config["input_dim"], *config["hidden"], config["num_classes"]]
-
-
-def row_floats(config: dict) -> int:
-    w = widths(config)
-    return sum(a * b + b for a, b in zip(w[:-1], w[1:]))
-
-
-def train_samples(config: dict) -> int:
-    """Training samples per client after the generator's test split."""
-    spc = config["samples_per_client"]
-    n_total = spc + max(1, int(spc * 0.2))
-    return n_total - max(1, int(n_total * 0.2))
-
-
-def layer_macs(config: dict) -> list[int]:
-    w = widths(config)
-    return [a * b for a, b in zip(w[:-1], w[1:])]
-
-
-def macs_per_sample(config: dict) -> int:
-    return sum(layer_macs(config))
-
-
-def train_flops_per_upload(config: dict, *, head_only: bool = False) -> int:
-    """Forward and backward of every epoch's full batch. Per sample: 2 FLOPs
-    per MAC forward; backward, 2 per MAC for the weight gradients of the
-    layers that move and 2 per MAC to carry the gradient down to them (never
-    into the input). Partial fine-tuning moves the last layer alone."""
-    macs = layer_macs(config)
-    backward = 2 * macs[-1] if head_only else 2 * sum(macs) + 2 * sum(macs[1:])
-    return config["local_epochs"] * train_samples(config) * (2 * sum(macs) + backward)
 
 
 def ingest_chain_cost(steps: int, centers: int, dim: int) -> tuple[float, float]:

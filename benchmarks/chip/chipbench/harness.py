@@ -30,7 +30,7 @@ from typing import Any
 
 import numpy as np
 
-from . import counts, generators
+from . import generators
 
 
 class WindowClosed(Exception):
@@ -84,9 +84,10 @@ class Recorder:
     REHEARSAL_FACTOR = 1.25
     PROGRESS_S = 10.0
 
-    def __init__(self, *, config: dict, horizon: float, seconds: float, refine_every: int, phase: int,
+    def __init__(self, *, config: dict, model, horizon: float, seconds: float, refine_every: int, phase: int,
                  clock: CompileClock, trace_dir: str | None, rehearsal: bool = False):
         self.config = config
+        self.model = model
         self.rehearsal = rehearsal
         self.horizon = horizon
         self.seconds = seconds
@@ -243,7 +244,7 @@ class Recorder:
             self.window_trained += len(cids)
             for cid, (base, head, lr, ep), trained in zip(cids, bases, out[0]):
                 self.samples.train.append((cid, base, trained, head, lr, ep))
-                self.window_flops += counts.train_flops_per_upload(self.config, head_only=bool(head))
+                self.window_flops += self.model.train_flops_per_upload(self.config, head_only=bool(head))
         return out
 
     def train_client(self, fleet, cid, call):
@@ -253,7 +254,7 @@ class Recorder:
         if self.state == "open":
             self.window_trained += 1
             head = fleet.clients[fleet.index[cid]].partial_finetune
-            self.window_flops += counts.train_flops_per_upload(self.config, head_only=bool(head))
+            self.window_flops += self.model.train_flops_per_upload(self.config, head_only=bool(head))
         return out
 
     def set_models(self, call):
@@ -320,31 +321,24 @@ def _install(rec: Recorder) -> _Patches:
 
 
 # ------------------------------------------------------------------- build
-def build(config: dict, traffic: dict, seed: int, rec: Recorder):
+def build(cell, seed: int, rec: Recorder):
     """Clients, server and simulator of one cell: the traffic's fixed draw
-    of data, devices and initial model, relabelled by ``seed``."""
+    of data, devices and initial model, with the data and the model drawn
+    and permuted by ``seed`` in the configuration's client model."""
     import jax
 
-    from repro.configs.paper_tasks import PAPER_TASKS, MLPTaskConfig
     from repro.core.client import SimClient
     from repro.fl.experiment import build_strategy
     from repro.fl.network import NetworkModel
     from repro.fl.simulator import Simulator
-    from repro.fl.tasks import MLP_TASK
 
-    task = config["task"]
-    mlp = MLPTaskConfig(task, config["input_dim"], tuple(config["hidden"]), config["num_classes"])
-    if PAPER_TASKS[task] != mlp:
-        raise ValueError(f"config widths {mlp} differ from the program's {PAPER_TASKS[task]}")
+    config, traffic = cell.config, cell.traffic
     n = config["num_clients"]
     draw = traffic["trajectory_seed"]
     pseed = program_seed(draw)
     rng = np.random.default_rng(draw)
-    data = generators.make_task(task, n, rng, latent_clusters=config["latent_clusters"],
-                                samples_per_client=config["samples_per_client"])
+    data, init, client = cell.model.draw(config, rng, pseed, seed)
     devices = generators.make_device_fleet(n, rng, config["device_mix"], config["base_round_time_s"])
-    init = MLP_TASK.init_params(jax.random.PRNGKey(pseed), mlp)
-    data, init = generators.relabel(data, init, seed)
     init = jax.device_put(init)
 
     def timed(round_time_of):
@@ -354,9 +348,8 @@ def build(config: dict, traffic: dict, seed: int, rec: Recorder):
         return round_time
 
     clients = [
-        SimClient(client_id=i, data=data[i], num_classes=config["num_classes"],
-                  device_class=devices[i]["class"], round_time_fn=timed(devices[i]["round_time"]),
-                  local_epochs=config["local_epochs"], lr=config["lr"])
+        SimClient(client_id=i, data=data[i], device_class=devices[i]["class"],
+                  round_time_fn=timed(devices[i]["round_time"]), **client)
         for i in range(n)
     ]
     server = build_strategy(
@@ -396,7 +389,7 @@ def _pass(cell, seed: int, seconds: float, clock: CompileClock, *, trace_dir, re
 
     n = cell.config["num_clients"]
     phase = int(np.random.default_rng(seed).integers(1 << 30))
-    rec = Recorder(config=cell.config, horizon=cell.traffic["warmup_horizon_s"], seconds=seconds,
+    rec = Recorder(config=cell.config, model=cell.model, horizon=cell.traffic["warmup_horizon_s"], seconds=seconds,
                    refine_every=max(20, n // 4), phase=phase, clock=clock, trace_dir=trace_dir,
                    rehearsal=rehearsal)
     patches = _install(rec)
@@ -410,7 +403,7 @@ def _pass(cell, seed: int, seconds: float, clock: CompileClock, *, trace_dir, re
     ops.ingest_chain = chain_probe
     try:
         rec.t_build = time.perf_counter()
-        data, clients, server, sim = build(cell.config, cell.traffic, seed, rec)
+        data, clients, server, sim = build(cell, seed, rec)
         rec.t_built = time.perf_counter()
         try:
             sim.run_async(max_time=1e18)
@@ -452,7 +445,7 @@ def run(cell, seed: int, seconds: float, *, trace_dir: str | None, t_process: fl
     compiles or loads from the persistent cache, every shape the timed
     window will use, then the timed pass, which replays the same trajectory
     with nothing left to compile. Both count as set-up. Every seed replays
-    the traffic's fixed draw relabelled (``generators.relabel``), so the
+    the traffic's fixed draw, permuted by the client model, so the
     shapes are the same from seed to seed and only a checkout's first run
     compiles them."""
     import gc

@@ -6,18 +6,19 @@ import contextlib
 import json
 import time
 
-from .spec import BENCH_DIR, load_cell
+from .spec import BENCH_DIR, ROOT, load_cell
 
 SEED = 2**33 + 5  # wider than 32 bits, as seeds may be
 
 
 def small_cell(workload: str, *, traffic: str | None = None, clients: int = 64,
-               horizon: float = 120.0):
+               horizon: float = 120.0, bench_dir=BENCH_DIR, root=ROOT):
     """``workload`` cut to ``clients``; ``traffic`` swaps in another mix from
-    ``traffic/`` (a mix no cell of ``BENCHMARK.json`` uses yet)."""
-    cell = load_cell(workload)
+    ``traffic/`` (a mix no cell of ``BENCHMARK.json`` uses yet). ``bench_dir``
+    and ``root`` point at another copy of the benchmark."""
+    cell = load_cell(workload, bench_dir=bench_dir, root=root)
     if traffic is not None:
-        cell.traffic = json.loads((BENCH_DIR / "traffic" / f"{traffic}.json").read_text())
+        cell.traffic = json.loads((bench_dir / "traffic" / f"{traffic}.json").read_text())
     cell.config["num_clients"] = clients
     cell.traffic["warmup_horizon_s"] = horizon
     return cell
@@ -50,3 +51,14 @@ def patched(obj, name, make):
         yield
     finally:
         setattr(obj, name, orig)
+
+
+@contextlib.contextmanager
+def fixed_window(supersteps: int):
+    """Close the window, and the rehearsal's, after ``supersteps`` window
+    supersteps in place of a wall time, so that two runs of one seed ingest
+    the same work and compare the same samples."""
+    from .harness import Recorder
+
+    with patched(Recorder, "_window_over", lambda _: lambda self, now: self.window_steps >= supersteps):
+        yield

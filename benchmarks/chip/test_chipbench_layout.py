@@ -1,7 +1,8 @@
-"""The benchmark's files: every configuration, traffic mix and metric loads
-by the name ``BENCHMARK.json`` gives it; a new mix and a new metric are
-found as new files with no edit; the count functions agree with hand
-counts; the command refuses to run without a TPU."""
+"""The benchmark's files: every configuration, traffic mix, client model and
+metric loads by the name ``BENCHMARK.json`` or the configuration gives it; a
+new mix and a new metric are found as new files with no edit; the count
+functions agree with hand counts; the command refuses to run without a
+TPU."""
 from __future__ import annotations
 
 import json
@@ -20,6 +21,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parents[1]
 CELL = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"][0]["name"]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+MLP = spec.client_model("mlp")
 
 
 def _bench():
@@ -60,15 +62,39 @@ def test_entries_keep_the_contract():
         assert len(w["why"]) <= 200 and w["chips"] == 1
 
 
-def test_config_widths_match_the_program():
-    from repro.configs.paper_tasks import PAPER_TASKS
-
+def test_every_config_names_a_client_model():
     for c in _bench()["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
-        task = PAPER_TASKS[cfg["task"]]
-        assert (task.input_dim, list(task.hidden), task.num_classes) == (
-            cfg["input_dim"], cfg["hidden"], cfg["num_classes"])
-        assert counts.row_floats(cfg) == cfg["row_floats"]
+        path = BENCH / "models" / f"{cfg['client_model']}.py"
+        assert path.is_file(), path
+        model = spec.client_model(cfg["client_model"])
+        for name in spec.MODEL_FUNCTIONS:
+            assert callable(getattr(model, name)), (cfg["client_model"], name)
+        assert (BENCH / cfg["reference"]).is_file()
+
+
+def test_a_config_without_a_client_model_is_refused(tmp_path):
+    shutil.copytree(BENCH, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__", "out"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    bench_dir = tmp_path / "benchmarks" / "chip"
+    path = bench_dir / "configs" / f"{_bench()['workloads'][0]['config']}.json"
+    cfg = json.loads(path.read_text())
+    del cfg["client_model"]
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match="client_model"):
+        spec.load_cell(CELL, bench_dir=bench_dir, root=tmp_path)
+    (bench_dir / "models" / "bare.py").write_text("def draw(config, rng, program_seed, seed):\n    pass\n")
+    with pytest.raises(AttributeError, match="row_floats"):
+        spec.client_model("bare", bench_dir=bench_dir)
+
+
+def test_config_widths_match_the_program():
+    for c in _bench()["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        model = spec.client_model(cfg["client_model"])
+        model.check_sizes(cfg)  # raises where the widths are not the program's
+        assert model.row_floats(cfg) == cfg["row_floats"]
 
 
 def test_a_new_mix_and_metric_are_found_as_new_files(tmp_path):
@@ -113,12 +139,12 @@ HAND = {  # the paper's two client models as the repository builds them
                                                   128 * 128 + 128 * 64 + 64 * 10, 128 * 64 + 64 * 10)])
 def test_counts_match_hand_counts(name, row, macs, below):
     cfg = HAND[name]
-    assert counts.row_floats(cfg) == row
-    assert counts.macs_per_sample(cfg) == macs
-    assert counts.train_samples(cfg) == 92  # 96 + 19 generated, 23 held out for test
-    assert counts.train_flops_per_upload(cfg) == 5 * 92 * (2 * macs + 2 * macs + 2 * below)
-    head = counts.layer_macs(cfg)[-1]
-    assert counts.train_flops_per_upload(cfg, head_only=True) == 5 * 92 * (2 * macs + 2 * head)
+    assert MLP.row_floats(cfg) == row
+    assert MLP.macs_per_sample(cfg) == macs
+    assert MLP.train_samples(cfg) == 92  # 96 + 19 generated, 23 held out for test
+    assert MLP.train_flops_per_upload(cfg) == 5 * 92 * (2 * macs + 2 * macs + 2 * below)
+    head = MLP.layer_macs(cfg)[-1]
+    assert MLP.train_flops_per_upload(cfg, head_only=True) == 5 * 92 * (2 * macs + 2 * head)
     ops, nbytes = counts.ingest_chain_cost(100, 4, row)
     assert nbytes == 4 * (100 * row + 2 * 4 * row + 100 * row + 4 * 100)
     assert ops == 100 * (3 * 4 * row + 3 * row + 9 * row)
