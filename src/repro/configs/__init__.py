@@ -8,6 +8,7 @@ from repro.configs.base import (
     MoESpec,
     ShapeSpec,
     TrainSpec,
+    YarnSpec,
     get_config,
     register_arch,
     supports_shape,
@@ -39,6 +40,7 @@ __all__ = [
     "MambaSpec",
     "ModelConfig",
     "MoESpec",
+    "YarnSpec",
     "ShapeSpec",
     "TrainSpec",
     "get_config",

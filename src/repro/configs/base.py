@@ -27,11 +27,24 @@ class LayerSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
+    """A mixture-of-experts FFN. ``num_experts`` is the router's width: the
+    experts every token is routed over. A chip of an expert-parallel
+    deployment holds the ``held`` experts from id ``held_first`` on (all of
+    them when ``held`` is None) and computes their part of the result."""
+
     num_experts: int
     top_k: int
     d_expert: int
     num_shared: int = 0  # shared (always-on) experts, DeepSeek-style
     router_noise: float = 0.0
+    norm_topk_prob: bool = True  # renormalize the top-k gate weights to sum 1
+    routed_scaling_factor: float = 1.0
+    held_first: int = 0
+    held: int | None = None
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else self.held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,6 +56,18 @@ class MLASpec:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     q_lora_rank: int | None = None  # V2-Lite projects q directly
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnSpec:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,16 +117,18 @@ class ModelConfig:
     is_encoder: bool = False
     sliding_window: int = 4096
     rope_theta: float = 10000.0
+    rope_scaling: YarnSpec | None = None
     final_logit_softcap: float | None = None
     attn_logit_softcap: float | None = None
     query_pre_attn_scalar: float | None = None  # gemma2: fixed 1/sqrt(256) scale
     use_post_norm: bool = False  # gemma2 applies RMSNorm after mixer/ffn too
     tie_embeddings: bool = False
     embeds_input: bool = False  # vlm/audio: frontend stub feeds embeddings
-    # dropless MoE: expert capacity = group size, so no token ever overflows.
-    # Decode (1 token/step) is naturally dropless; enabling this makes the
-    # full forward bit-consistent with incremental decode (serving/test mode;
-    # training keeps capacity_factor dispatch for efficiency).
+    # dropless MoE: every (token, expert) pair routed to a held expert is
+    # computed (``layers.apply_moe_held``), so no token ever overflows. It
+    # makes the full forward consistent with incremental decode, and is the
+    # only layer that holds a share of the experts; without it training
+    # keeps capacity_factor dispatch over all experts.
     moe_dropless: bool = False
     norm_eps: float = 1e-6
     train: TrainSpec = TrainSpec()
@@ -159,7 +186,7 @@ class ModelConfig:
             n += self._mixer_params(layer.mixer, d, hd)
             if layer.ffn == "moe":
                 assert self.moe is not None
-                active = self.moe.top_k + self.moe.num_shared
+                active = min(self.moe.top_k, self.moe.num_held) + self.moe.num_shared
                 n += active * 3 * d * self.moe.d_expert + d * self.moe.num_experts
             else:
                 n += self._ffn_params(layer.ffn, d)
@@ -210,7 +237,7 @@ class ModelConfig:
             return 3 * d * self.d_ff  # swiglu: gate, up, down
         if ffn == "moe":
             assert self.moe is not None
-            total = (self.moe.num_experts + self.moe.num_shared) * 3 * d * self.moe.d_expert
+            total = (self.moe.num_held + self.moe.num_shared) * 3 * d * self.moe.d_expert
             total += d * self.moe.num_experts  # router
             return total
         if ffn == "none":

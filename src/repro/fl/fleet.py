@@ -37,6 +37,14 @@ live-read semantics.
 Cohort launches pad to the next power of two (extra rows get a zero epoch
 budget), so the jit cache holds O(log clients) entries instead of one per
 cohort size, and the dispatch count stays flat as the fleet grows.
+
+The task's ``operands`` (a frozen base) ride every launch as an argument.
+A launch whose cohort would not fit the device runs it in client blocks, a
+``lax.map`` over blocks inside the one launch; the block is the largest
+power of two of clients whose activations (``task.client_bytes``) fit half
+of the device's memory (the rest holds the base, the planes and the
+launch's operands), so a small model's cohort stays one block and its
+program is the unblocked one.
 """
 from __future__ import annotations
 
@@ -60,37 +68,77 @@ def _pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "max_epochs", "task"))
-def _train_launch(mat, train, gather, lr, epochs, head, *,
-                  spec: FlattenSpec, max_epochs: int, task):
+@functools.cache
+def _device_bytes() -> int | None:
+    """The first device's memory, where its backend reports it."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def _block(task, kind: str, data: dict, rows: int) -> int:
+    """Clients per block of a ``kind`` launch over ``rows`` clients: the
+    largest power of two whose activations fit half the device's memory,
+    or all ``rows`` where they fit or the device reports none."""
+    limit = _device_bytes()
+    per = task.client_bytes(kind, data)
+    if limit is None or rows * per <= limit // 2:
+        return rows
+    return max(1, 1 << ((limit // 2) // max(per, 1)).bit_length() - 1)
+
+
+def _blocked(fn, block: int, *args):
+    """``fn(*args)`` over leaves with a leading row axis, in blocks of
+    ``block`` rows (a ``lax.map``; the last block padded with row 0); one
+    block calls ``fn`` directly."""
+    rows = jax.tree_util.tree_leaves(args)[0].shape[0]
+    if block >= rows:
+        return fn(*args)
+    nb = -(-rows // block)
+
+    def split(x):
+        if nb * block > rows:
+            x = jnp.concatenate([x, jnp.broadcast_to(x[:1], (nb * block - rows, *x.shape[1:]))])
+        return x.reshape(nb, block, *x.shape[1:])
+
+    out = lax.map(lambda a: fn(*a), jax.tree_util.tree_map(split, args))
+    return jax.tree_util.tree_map(lambda x: x.reshape(nb * block, *x.shape[2:])[:rows], out)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "max_epochs", "task", "block"))
+def _train_launch(mat, train, gather, lr, epochs, head, ops, *,
+                  spec: FlattenSpec, max_epochs: int, task, block: int):
     # the cohort's data-row gather happens inside the launch, fused with the
     # training compute — no materialized (P, n, ...) copies per round. The
     # whole train dict is gathered; tensors the task never reads (e.g. the
     # MLP feedback path ignoring labels) are pruned by XLA DCE.
     d = {k: v[gather] for k, v in train.items()}
-    params_b = jax.vmap(spec._unflatten)(mat)
-    new_b, losses = task.fleet_local_train(
-        params_b, d, lr, epochs, head, max_epochs=max_epochs
-    )
-    return jax.vmap(spec._flatten)(new_b), losses
+
+    def run(mat, d, lr, epochs, head):
+        params_b = jax.vmap(spec._unflatten)(mat)
+        new_b, losses, counts = task.fleet_local_train(
+            ops, params_b, d, lr, epochs, head, max_epochs=max_epochs
+        )
+        return jax.vmap(spec._flatten)(new_b), losses, counts
+
+    return _blocked(run, block, mat, d, lr, epochs, head)
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "max_epochs", "task"))
-def _train_launch_bank(bank, sel, train, gather, lr, epochs, head, *,
-                       spec: FlattenSpec, max_epochs: int, task):
+@functools.partial(jax.jit, static_argnames=("spec", "max_epochs", "task", "block"))
+def _train_launch_bank(bank, sel, train, gather, lr, epochs, head, ops, *,
+                       spec: FlattenSpec, max_epochs: int, task, block: int):
     # row-sliced variant: the model matrix is gathered from the fleet's
     # model-row bank INSIDE the launch. An eager per-call gather of dozens
     # of scattered plane rows is the slow path on CPU (that is why the
     # plane caches views); in-jit it compiles once and fuses with training.
     return _train_launch.__wrapped__(
-        bank[sel], train, gather, lr, epochs, head,
-        spec=spec, max_epochs=max_epochs, task=task,
+        bank[sel], train, gather, lr, epochs, head, ops,
+        spec=spec, max_epochs=max_epochs, task=task, block=block,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "task"))
-def _eval_launch(mat, test, *, spec: FlattenSpec, task):
-    return task.fleet_evaluate(jax.vmap(spec._unflatten)(mat), test)
+@functools.partial(jax.jit, static_argnames=("spec", "task", "block"))
+def _eval_launch(mat, test, ops, *, spec: FlattenSpec, task, block: int):
+    return _blocked(lambda m, t: task.fleet_evaluate(ops, jax.vmap(spec._unflatten)(m), t), block, mat, test)
 
 
 # A downlink batch fans a few distinct payloads out over many rows. Its
@@ -127,15 +175,19 @@ def _host_leaves(params: PyTree) -> list | None:
     return None
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "num_classes", "task"))
-def _feedback_launch(bank, sel, train, gather, *, spec: FlattenSpec,
-                     num_classes: int, task):
+@functools.partial(jax.jit, static_argnames=("spec", "num_classes", "task", "block"))
+def _feedback_launch(bank, sel, train, gather, ops, *, spec: FlattenSpec,
+                     num_classes: int, task, block: int):
     # a probe sweep pairs hundreds of members against a handful of DISTINCT
     # centers: the (pairs, dim) probe matrix is expanded from the small
-    # center bank inside the launch, never materialized eagerly
-    mat = bank[sel]
-    d = {k: v[gather] for k, v in train.items()}
-    return task.fleet_feedback(jax.vmap(spec._unflatten)(mat), d, num_classes)
+    # center bank inside the launch, never materialized eagerly (a block's
+    # probes and data rows are gathered inside its step)
+    def run(sel, gather):
+        mat = bank[sel]
+        d = {k: v[gather] for k, v in train.items()}
+        return task.fleet_feedback(ops, jax.vmap(spec._unflatten)(mat), d, num_classes)
+
+    return _blocked(run, block, sel, gather)
 
 
 class ClientFleet:
@@ -183,6 +235,8 @@ class ClientFleet:
                 ndim, NamedSharding(mesh, PartitionSpec("plane", *(None,) * (ndim - 1)))
             )
             self._replicated = NamedSharding(mesh, PartitionSpec())
+        # the task's device operands, placed once (replicated under a mesh)
+        self._ops = jax.tree_util.tree_map(self._rep, self.task.operands)
         self.plane = ParameterPlane(template, capacity=2 * K, mesh=mesh)
         self._model_row = [self.plane.alloc() for _ in range(K)]
         self._eval_row = [self.plane.alloc() for _ in range(K)]
@@ -228,6 +282,7 @@ class ClientFleet:
         self._train_data = fd.train
         self._test_data = fd.test
         self.f_true = fd.f_true
+        self._tokens = fd.tokens
 
     def _sync_data(self) -> None:
         """Match the loop backend's live-read semantics: a replaced
@@ -328,10 +383,10 @@ class ClientFleet:
 
     def _train(self, idx: np.ndarray, mat: jax.Array | None, lr, epochs, head, *,
                bank: jax.Array | None = None):
-        """Shared padded launch: returns device (S, dim) vecs + (S,) losses.
-        ``mat`` is an explicit (S, dim) model matrix; alternatively pass
-        ``bank`` (the full model-row view) and the rows ``idx`` select are
-        gathered inside the launch."""
+        """Shared padded launch: returns device (S, dim) vecs, (S,) losses
+        and the task's per-client counters. ``mat`` is an explicit (S, dim)
+        model matrix; alternatively pass ``bank`` (the full model-row view)
+        and the rows ``idx`` select are gathered inside the launch."""
         self._sync_data()
         S = len(idx)
         P = _pow2(S)
@@ -350,18 +405,27 @@ class ClientFleet:
             self._rep(lr),
             self._rep(epochs),
             self._rep(head),
+            self._ops,
         )
+        kw = dict(spec=self.spec, max_epochs=max_epochs, task=self.task,
+                  block=_block(self.task, "train", self._train_data, P))
         if bank is not None:
-            vecs, losses = _train_launch_bank(
-                self._rep(bank), self._rep(idx), *args,
-                spec=self.spec, max_epochs=max_epochs, task=self.task,
-            )
+            vecs, losses, counts = _train_launch_bank(self._rep(bank), self._rep(idx), *args, **kw)
         else:
-            vecs, losses = _train_launch(
-                self._rep(mat), *args,
-                spec=self.spec, max_epochs=max_epochs, task=self.task,
-            )
-        return vecs[:S], losses[:S]
+            vecs, losses, counts = _train_launch(self._rep(mat), *args, **kw)
+        return vecs[:S], losses[:S], {k: v[:S] for k, v in counts.items()}
+
+    def _trained_tokens(self, idx, epochs) -> int:
+        """Tokens the clients ``idx`` train in ``epochs`` (a task that
+        trains on tokens)."""
+        return int(np.sum(self._tokens[np.asarray(idx)] * np.asarray(epochs)))
+
+    @staticmethod
+    def _record_counts(counts: dict) -> None:
+        """The launch's fetched counters as zero-length spans."""
+        if "routed" in counts:
+            with span("train/routed", pairs=int(np.sum(counts["routed"]))):
+                pass
 
     def train_cohort(
         self, cids: Sequence[Any], params_list: Sequence[PyTree], *,
@@ -380,8 +444,9 @@ class ClientFleet:
             self.model_vec(c) if p is None else self._vec_of(p)
             for c, p in zip(cids, params_list)
         ])
-        vecs, losses = self._train(idx, mat, *self._train_specs(cids))
-        vecs_np, losses_np = fetch((vecs, losses), "fleet_train")
+        vecs, losses, counts = self._train(idx, mat, *self._train_specs(cids))
+        vecs_np, losses_np, counts = fetch((vecs, losses, counts), "fleet_train")
+        self._record_counts(counts)
         # the per-client leaves are views over this one base matrix: freeze
         # it so an (unsupported) in-place mutation raises, exactly like the
         # immutable jax-array leaves the loop path hands out
@@ -395,9 +460,12 @@ class ClientFleet:
         this client's model row, writes the new row back, and returns the
         updated params as a pytree plus the device-scalar loss."""
         i = self.index[cid]
-        with span("train/launch", rows=1):
+        specs = self._train_specs([cid])
+        with span("train/launch", rows=1) as sp:
+            if self._tokens is not None:
+                sp.set_metadata(tokens=self._trained_tokens([i], specs[1]))
             mat = self.model_vec(cid)[None, :]
-            vecs, losses = self._train(np.asarray([i]), mat, *self._train_specs([cid]))
+            vecs, losses, _ = self._train(np.asarray([i]), mat, *specs)
         vec = vecs[0]
         self.plane.write(self._model_row[i], vec)
         self._has_model[i] = True
@@ -422,14 +490,18 @@ class ClientFleet:
         # incrementally); the batch's rows are gathered from it inside the
         # launch — an eager scattered-row gather per window is the slow
         # path on CPU
-        with span("train/launch", rows=len(cids)):
+        specs = self._train_specs(cids)
+        with span("train/launch", rows=len(cids)) as sp:
+            if self._tokens is not None:
+                sp.set_metadata(tokens=self._trained_tokens(idx, specs[1]))
             bank = self.plane.rows(tuple(self._model_row))
-            vecs, losses = self._train(idx, None, *self._train_specs(cids), bank=bank)
+            vecs, losses, counts = self._train(idx, None, *specs, bank=bank)
         self.plane.write_rows([self._model_row[i] for i in idx], vecs)
         for i in idx:
             self._has_model[i] = True
             self._model_ver[i] += 1
-        vecs_np, losses_np = fetch((vecs, losses), "fleet_train")
+        vecs_np, losses_np, counts = fetch((vecs, losses, counts), "fleet_train")
+        self._record_counts(counts)
         vecs_np = np.asarray(vecs_np)
         vecs_np.flags.writeable = False  # leaves are views: freeze like train_cohort
         out = [self.to_pytree_np(v) for v in vecs_np], losses_np
@@ -468,7 +540,9 @@ class ClientFleet:
         # so it can share the launch with the client-sharded data tensors)
         mat = plane.rows(tuple(self._eval_row), on_mesh=self.mesh is not None)
         self.launches += 1
-        accs = fetch(_eval_launch(mat, self._test_data, spec=self.spec, task=self.task), "eval")
+        block = _block(self.task, "eval", self._test_data, mat.shape[0])
+        accs = fetch(_eval_launch(mat, self._test_data, self._ops, spec=self.spec, task=self.task,
+                                  block=block), "eval")
         if zero.any():
             accs = np.where(zero, 0.0, accs)
         return accs
@@ -494,19 +568,23 @@ class ClientFleet:
                 slot = bank_ids[key] = len(bank_vecs)
                 bank_vecs.append(self._vec_of(center))
             sel[k] = slot
-        B = _pow2(len(bank_vecs))  # pow2-padded bank: O(log centers) jit cache
-        bank_vecs += [bank_vecs[0]] * (B - len(bank_vecs))
-        bank = jnp.stack(bank_vecs)
         M = len(pairs)
         P = _pow2(M)
+        block = _block(self.task, "feedback", self._train_data, P)
+        # pow2-padded bank: O(log centers) jit cache; a launch too large
+        # for one block (a costly compile) pads to at least 8, one program
+        # per probe count
+        B = _pow2(len(bank_vecs)) if block >= P else _bank(len(bank_vecs))
+        bank_vecs += [bank_vecs[0]] * (B - len(bank_vecs))
+        bank = jnp.stack(bank_vecs)
         gather = idx
         if P != M:
             gather = np.concatenate([idx, np.full(P - M, idx[0])])
             sel = np.concatenate([sel, np.full(P - M, sel[0], np.int32)])
         self.launches += 1
         f_pred, s_soft = _feedback_launch(
-            self._rep(bank), self._rep(sel), self._train_data, self._rep(gather),
-            spec=self.spec, num_classes=self.num_classes, task=self.task,
+            self._rep(bank), self._rep(sel), self._train_data, self._rep(gather), self._ops,
+            spec=self.spec, num_classes=self.num_classes, task=self.task, block=block,
         )
         f_pred, s_soft = fetch((f_pred[:M], s_soft[:M]), "feedback")
         return np.asarray(f_pred), self.f_true[idx], np.asarray(s_soft)

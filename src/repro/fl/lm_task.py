@@ -1,21 +1,32 @@
 """REPRO_TASK=lm: personalized LM fine-tuning as plane rows.
 
-Each simulated device personalizes a FROZEN transformer base (the
-``tiny_lm`` config by default) by training a small delta pytree:
+Each simulated device personalizes a FROZEN transformer base by training a
+small delta pytree of LoRA factors, applied to activations beside the
+frozen weights (``y = x W + s (x a) b``, ``s = 1/r``; ``layers.lora``) and
+never merged into per-client weights, so every client of a launch shares
+one base matmul:
 
-* ``head_a``/``head_b`` — a LoRA factorization of the output head. With
-  tied embeddings the update merges into the embedding matrix, so it
-  personalizes both the input lookup and the logits (the tied-weight
-  analogue of a per-client classifier head).
-* ``wq`` — per-slot LoRA on the attention query projections of the
-  scanned blocks, so local training runs the flash-attention kernels
-  forward AND backward, not just a linear probe over frozen features.
+* ``head_a``/``head_b`` — LoRA on the output head's logits (the LM
+  analogue of a per-client classifier head); with tied embeddings the
+  same update moves each token's input row too, as a merged tied head
+  would;
+* one LoRA per adapter target (``targets``: ``wq``, and for latent
+  attention also ``w_dkv``, the latent down-projection) on every attention
+  layer: ``<target>/slot<i>`` stacked over the scanned periods, and
+  ``prefix/layer<i>/<target>`` for the leading unrolled layers. Local
+  training then runs attention forward AND backward, not just a probe.
 
-Only the delta rides the wire and becomes a plane row: the base lives in
-a :class:`FrozenBase` (a ``register_static`` pytree wrapper with zero
-array leaves), so ``simulator.model_bytes`` bills uploads/downlinks at
-delta size automatically and the server's clustering plane stores
-``dim = size(delta)`` rows, not ``size(base)``.
+Only the delta rides the wire and becomes a plane row. The task holds the
+base in a :class:`FrozenBase` (a ``register_static`` pytree wrapper with
+zero array leaves), so ``simulator.model_bytes`` bills uploads/downlinks at
+delta size and the server's plane stores ``dim = size(delta)`` rows. The
+fleet passes the base into every launch as a device operand
+(:attr:`LMTask.operands`); it is never folded into a program as a constant.
+
+The per-client arithmetic is written for a batch of clients: every delta
+leaf carries a leading client axis, the clients' sequences are one
+client-major batch of the forward, and the loss is the sum of the clients'
+mean NLLs, so each client's gradient is its own.
 
 Per-client data is a token stream (:mod:`repro.data.lm`): clients in the
 same latent cluster share one Zipf+Markov distribution (same support
@@ -46,6 +57,7 @@ from repro.models.model import forward as model_forward
 from repro.models.model import init_params as model_init_params
 
 PyTree = Any
+F32 = 4
 
 
 @jax.tree_util.register_static
@@ -56,9 +68,10 @@ class FrozenBase:
     ``register_static`` makes it flatten to ZERO leaves (the whole object
     is treedef metadata), which is what keeps the base out of every
     leaf-walking code path at once: ``model_bytes`` bills payloads that
-    carry it at 0 bytes, ``flatten_spec`` rows exclude it, and jit treats
-    it as a compile-time constant. ``eq=False`` gives identity hash/eq —
-    comparing multi-MB pytrees per jit-cache lookup would be absurd."""
+    carry it at 0 bytes and ``flatten_spec`` rows exclude it. ``eq=False``
+    gives identity hash/eq — comparing multi-MB pytrees per jit-cache lookup
+    would be absurd. Launches take ``params`` as an argument
+    (:attr:`LMTask.operands`)."""
 
     params: PyTree
 
@@ -88,126 +101,186 @@ class LMClientData:
         ).astype(np.float64)
 
 
+def _per_client(v: jax.Array, leaf: jax.Array) -> jax.Array:
+    """A (C,) per-client value broadcast against a leaf with a leading
+    client axis."""
+    return v.reshape(v.shape + (1,) * (leaf.ndim - 1))
+
+
 @dataclasses.dataclass(frozen=True)
 class LMTask:
     """PersonalizationTask over LoRA/head deltas on a frozen base.
 
-    Frozen + hashable: ``base`` hashes by identity (FrozenBase), ``cfg``
-    by value, so the fleet's static-task jit cache keys correctly."""
+    Frozen + hashable by value of everything but the base (``cfg``, rank,
+    targets, buckets), so the fleet's static-task jit cache keys on the
+    shapes: launches take the base as an operand (:attr:`operands`), and two
+    tasks over different draws of one config share their programs."""
 
-    base: FrozenBase
+    base: FrozenBase = dataclasses.field(compare=False)
     cfg: ModelConfig
     lora_rank: int = 4
     buckets: int = 16
+    targets: tuple[str, ...] = ("wq",)
     name: str = "lm"
 
+    @classmethod
+    def build(cls, cfg: ModelConfig, seed: int, *, lora_rank: int = 4, targets: tuple[str, ...] = ("wq",),
+              dtype=jnp.float32, buckets: int = 16) -> "LMTask":
+        """A task over a base of ``cfg`` drawn from ``seed`` and stored as
+        ``dtype``, with rank-``lora_rank`` adapters on ``targets``."""
+        base = jax.jit(model_init_params, static_argnums=(0, 2))(cfg, jax.random.PRNGKey(seed), dtype)
+        return cls(base=FrozenBase(base), cfg=cfg, lora_rank=lora_rank, buckets=buckets,
+                   targets=tuple(targets))
+
+    @property
+    def operands(self) -> PyTree:
+        """The device arrays every launch takes: the frozen base."""
+        return self.base.params
+
+    @property
+    def lora_scale(self) -> float:
+        return 1.0 / self.lora_rank
+
     # ---- delta pytree ---------------------------------------------------
+    def _target_width(self, target: str) -> int:
+        cfg = self.cfg
+        if target == "wq":
+            qk = cfg.resolved_head_dim if cfg.mla is None else cfg.mla.qk_nope_head_dim + cfg.mla.qk_rope_head_dim
+            return cfg.num_heads * qk
+        if target == "w_dkv" and cfg.mla is not None:
+            return cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
+        raise ValueError(f"no adapter target {target!r} in {cfg.name}")
+
     def init_params(self, key: jax.Array) -> PyTree:
         cfg, r = self.cfg, self.lora_rank
         d, V, P = cfg.d_model, cfg.padded_vocab, cfg.num_periods
-        k_head, k_wq = jax.random.split(key)
+        k_head, k_ad = jax.random.split(key)
         delta: dict[str, Any] = {
             # standard LoRA init: a random, b zero — the initial delta is an
             # exact zero update, so initial rows sit at the plane origin
             "head_a": jax.random.normal(k_head, (d, r), jnp.float32) / np.sqrt(d),
             "head_b": jnp.zeros((r, V), jnp.float32),
-            "wq": {},
         }
-        for i, spec in enumerate(cfg.pattern):
+
+        def factors(k, width, lead=()):
+            return {"a": jax.random.normal(k, (*lead, d, r), jnp.float32) / np.sqrt(d),
+                    "b": jnp.zeros((*lead, r, width), jnp.float32)}
+
+        prefix = {}
+        for i, spec in enumerate(cfg.prefix):
             if spec.mixer in ("attn", "attn_local"):
-                k_wq, k = jax.random.split(k_wq)
-                hk = cfg.num_heads * cfg.resolved_head_dim
-                delta["wq"][f"slot{i}"] = {
-                    "a": jax.random.normal(k, (P, d, r), jnp.float32) / np.sqrt(d),
-                    "b": jnp.zeros((P, r, hk), jnp.float32),
-                }
+                layer = {}
+                for t in self.targets:
+                    k_ad, k = jax.random.split(k_ad)
+                    layer[t] = factors(k, self._target_width(t))
+                prefix[f"layer{i}"] = layer
+        if prefix:
+            delta["prefix"] = prefix
+        for t in self.targets:
+            delta[t] = {}
+            for i, spec in enumerate(cfg.pattern):
+                if spec.mixer in ("attn", "attn_local"):
+                    k_ad, k = jax.random.split(k_ad)
+                    delta[t][f"slot{i}"] = factors(k, self._target_width(t), (P,))
         return delta
 
-    def merged(self, delta: PyTree) -> PyTree:
-        """Base + delta as effective forward params (pure, jit-traceable;
-        the base leaves fold in as constants)."""
-        base = self.base.params
+    def _adapters(self, delta: PyTree) -> PyTree:
+        """The forward's adapter pytree for a batch of deltas (leading client
+        axis): scanned slots' factors get the period axis first."""
         cfg = self.cfg
-        scale = 1.0 / self.lora_rank
-        params = dict(base)
-        head_upd = (delta["head_a"] @ delta["head_b"]) * scale  # (d, V)
-        if cfg.tie_embeddings:
-            params["embed"] = base["embed"] + head_upd.T.astype(base["embed"].dtype)
-        else:
-            params["lm_head"] = base["lm_head"] + head_upd.astype(base["lm_head"].dtype)
-        if delta["wq"]:
-            blocks = dict(base["blocks"])
-            for slot, ab in delta["wq"].items():
-                sp = dict(blocks[slot])
-                mx = dict(sp["mixer"])
-                upd = jnp.einsum("pdr,prx->pdx", ab["a"], ab["b"]) * scale
-                mx["wq"] = mx["wq"] + upd.reshape(mx["wq"].shape).astype(mx["wq"].dtype)
-                sp["mixer"] = mx
-                blocks[slot] = sp
-            params["blocks"] = blocks
-        return params
+        ad: dict[str, Any] = {"head": {"a": delta["head_a"], "b": delta["head_b"]}}
+        if cfg.prefix:
+            prefix = delta.get("prefix", {})
+            ad["prefix"] = [prefix.get(f"layer{i}") for i in range(len(cfg.prefix))]
+        blocks: dict[str, Any] = {}
+        for t in self.targets:
+            for slot, ab in delta[t].items():
+                blocks.setdefault(slot, {})[t] = {k: jnp.swapaxes(v, 0, 1) for k, v in ab.items()}
+        ad["blocks"] = blocks
+        return ad
 
-    # ---- per-client arithmetic (the vmap operands) ----------------------
-    def _nll(self, delta, tokens, labels, seq_mask):
-        """Mean next-token NLL over valid sequences (padded rows masked)."""
-        logits, _, _ = model_forward(self.cfg, self.merged(delta), {"tokens": tokens})
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        per = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]  # (n, S)
-        per = per * seq_mask[:, None]
-        denom = jnp.maximum(jnp.sum(seq_mask) * tokens.shape[1], 1.0)
-        return -(jnp.sum(per) / denom)
+    # ---- per-client arithmetic, batched over clients -------------------
+    def _forward(self, base, delta, tokens):
+        """Logits (C, n, S, V) float32 and each position's count of pairs
+        routed to held experts (C, n, S) for the clients' sequences
+        ``tokens`` (C, n, S)."""
+        C, n, S = tokens.shape
+        embeds = jnp.take(base["embed"], tokens, axis=0).astype(jnp.float32)  # (C, n, S, d)
+        if self.cfg.tie_embeddings:
+            # the tied head's update is the embedding's too: each token's
+            # input row moves by s * a @ b[:, token]
+            b_tok = jax.vmap(lambda b, t: b[:, t])(delta["head_b"], tokens)  # (C, r, n, S)
+            embeds = embeds + self.lora_scale * jnp.einsum("crns,cdr->cnsd", b_tok, delta["head_a"])
+        embeds = embeds.reshape(C * n, S, -1)
+        logits, _, _, routed = model_forward(
+            self.cfg, base, {"embeds": embeds}, adapters=self._adapters(delta),
+            lora_scale=self.lora_scale, routed=True,
+        )
+        return logits.astype(jnp.float32).reshape(C, n, S, -1), routed.reshape(C, n, S)
 
-    def _scan_train(self, delta, tokens, labels, seq_mask, lr, epochs, head_frac,
+    def _nll(self, base, delta, tokens, labels, seq_mask):
+        """Per-client mean next-token NLL over valid sequences (padded rows
+        masked), and per-client routed pair counts."""
+        logits, routed = self._forward(base, delta, tokens)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        per = (lse - picked) * seq_mask[..., None]  # (C, n, S)
+        denom = jnp.maximum(jnp.sum(seq_mask, axis=1) * tokens.shape[2], 1.0)
+        counts = jnp.sum(routed * seq_mask[..., None].astype(routed.dtype), axis=(1, 2))
+        return jnp.sum(per, axis=(1, 2)) / denom, counts
+
+    def _scan_train(self, base, delta, tokens, labels, seq_mask, lr, epochs, head_frac,
                     max_epochs: int):
-        """Multi-epoch full-batch SGD on the delta, mirroring
-        ``mlp._scan_train``: steps past this client's ``epochs`` budget are
-        carried through untouched, and head-only fine-tuning selects the
-        block-LoRA gradients to exact zeros (the head LoRA is the LM
-        analogue of the MLP's last layer)."""
+        """Multi-epoch full-batch SGD on the clients' deltas, mirroring
+        ``mlp._scan_train``: steps past a client's ``epochs`` budget carry
+        it through untouched, and head-only fine-tuning selects the
+        attention LoRA gradients to exact zeros (the head LoRA is the LM
+        analogue of the MLP's last layer). Returns the deltas, the last
+        active epoch's losses and the pairs routed to held experts in the
+        active epochs' forward passes, per client."""
+
+        def loss_fn(p):
+            nll, routed = self._nll(base, p, tokens, labels, seq_mask)
+            return jnp.sum(nll), (nll, routed)
 
         def step(carry, e):
-            p, last_loss = carry
-            loss, grads = jax.value_and_grad(
-                lambda q: self._nll(q, tokens, labels, seq_mask)
-            )(p)
+            p, last_loss, routed_sum = carry
+            (_, (loss, routed)), grads = jax.value_and_grad(loss_fn, has_aux=True)(p)
             freeze_body = head_frac > 0
-            gw = jax.tree_util.tree_map(
-                lambda g: jnp.where(freeze_body, jnp.zeros_like(g), g), grads["wq"]
-            )
-            grads = {**grads, "wq": gw}
-            new = jax.tree_util.tree_map(lambda a, g: a - lr * g, p, grads)
+            grads = {k: g if k in ("head_a", "head_b") else jax.tree_util.tree_map(
+                lambda x: jnp.where(_per_client(freeze_body, x), jnp.zeros_like(x), x), g)
+                for k, g in grads.items()}
             active = e < epochs
             p2 = jax.tree_util.tree_map(
-                lambda old, nw: jnp.where(active, nw, old), p, new
-            )
-            return (p2, jnp.where(active, loss, last_loss)), None
+                lambda a, g: jnp.where(_per_client(active, a), a - _per_client(lr, a) * g, a), p, grads)
+            return (p2, jnp.where(active, loss, last_loss),
+                    routed_sum + jnp.where(active, routed, 0)), None
 
-        (delta, loss), _ = jax.lax.scan(
-            step, (delta, jnp.zeros(())), jnp.arange(max_epochs)
-        )
-        return delta, loss
+        C = tokens.shape[0]
+        init = (delta, jnp.zeros((C,), jnp.float32), jnp.zeros((C,), jnp.int32))
+        (delta, loss, routed), _ = jax.lax.scan(step, init, jnp.arange(max_epochs))
+        return delta, loss, routed
 
-    def _accuracy(self, delta, tokens, labels, seq_mask):
-        logits, _, _ = model_forward(self.cfg, self.merged(delta), {"tokens": tokens})
-        pred = jnp.argmax(logits, axis=-1)
-        correct = (pred == labels).astype(jnp.float32) * seq_mask[:, None]
-        denom = jnp.maximum(jnp.sum(seq_mask) * tokens.shape[1], 1.0)
-        return jnp.sum(correct) / denom
+    def _accuracy(self, base, delta, tokens, labels, seq_mask):
+        logits, _ = self._forward(base, delta, tokens)
+        correct = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32) * seq_mask[..., None]
+        denom = jnp.maximum(jnp.sum(seq_mask, axis=1) * tokens.shape[2], 1.0)
+        return jnp.sum(correct, axis=(1, 2)) / denom
 
-    def _distributions(self, delta, tokens, seq_mask, num_classes: int):
-        """(F_pred, S_soft) over ``token_id % J`` buckets: predicted-token
-        bucket counts and the mean bucket-aggregated softmax."""
+    def _distributions(self, base, delta, tokens, seq_mask, num_classes: int):
+        """(F_pred, S_soft) per client over ``token_id % J`` buckets:
+        predicted-token bucket counts and the mean bucket-aggregated
+        softmax."""
         J = num_classes
-        logits, _, _ = model_forward(self.cfg, self.merged(delta), {"tokens": tokens})
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # (n, S, V)
-        pred = jnp.argmax(logits, axis=-1)  # (n, S)
+        logits, _ = self._forward(base, delta, tokens)  # (C, n, S, V)
+        probs = jax.nn.softmax(logits, axis=-1)
+        pred = jnp.argmax(logits, axis=-1)
         bucket = jax.nn.one_hot(jnp.arange(logits.shape[-1]) % J, J)  # (V, J)
-        valid = seq_mask[:, None]  # (n, 1)
-        onehot = jax.nn.one_hot(pred % J, J) * valid[..., None]
-        hist = jnp.sum(onehot, axis=(0, 1))  # (J,) bucket counts
-        sprob = jnp.einsum("nsv,vj->nsj", probs, bucket) * valid[..., None]
-        denom = jnp.maximum(jnp.sum(seq_mask) * tokens.shape[1], 1.0)
-        return hist, jnp.sum(sprob, axis=(0, 1)) / denom
+        valid = seq_mask[..., None]  # (C, n, 1)
+        hist = jnp.sum(jax.nn.one_hot(pred % J, J) * valid[..., None], axis=(1, 2))
+        sprob = jnp.einsum("cnsv,vj->cnsj", probs, bucket) * valid[..., None]
+        denom = jnp.maximum(jnp.sum(seq_mask, axis=1) * tokens.shape[2], 1.0)
+        return hist, jnp.sum(sprob, axis=(1, 2)) / denom[:, None]
 
     # ---- fleet engine (batched; called inside the fleet's jits) ---------
     def build_fleet_data(self, datasets, shard, num_classes):
@@ -237,63 +310,88 @@ class LMTask:
         f_true = np.stack([
             d.label_histogram(num_classes).astype(np.float32) for d in datasets
         ])
-        return FleetData(train=train, test=test, f_true=f_true)
+        tokens = np.asarray([d.tokens_train.size for d in datasets], np.int64)
+        return FleetData(train=train, test=test, f_true=f_true, tokens=tokens)
 
-    def fleet_local_train(self, params_b, train, lr, epochs, head, *, max_epochs):
-        return jax.vmap(
-            functools.partial(self._scan_train, max_epochs=max_epochs)
-        )(params_b, train["tokens"], train["labels"], train["mask"], lr, epochs, head)
+    def client_bytes(self, kind: str, data: dict) -> int:
+        """Device bytes one client's share of a ``kind`` launch (``train``,
+        ``eval``, ``feedback``) holds at its peak, from the shapes alone, in
+        float32 values per token: for training, what every layer keeps for
+        the backward pass (two residual-stream rows and two rows of its FFN
+        hidden width: dense, or the shared experts and every pair the token
+        may route to held experts) and the logits with their softmax and
+        gradient; for a forward pass, the widest layer's and the logits."""
+        cfg = self.cfg
+        per_layer = []
+        for spec in cfg.all_layers:
+            hidden = 0
+            if spec.ffn == "dense":
+                hidden = cfg.d_ff
+            elif spec.ffn == "moe":
+                hidden = (cfg.moe.num_shared + min(cfg.moe.top_k, cfg.moe.num_held)) * cfg.moe.d_expert
+            per_layer.append(2 * cfg.d_model + 2 * hidden)
+        if kind == "train":
+            per_token = sum(per_layer) + 3 * cfg.padded_vocab
+        else:
+            per_token = max(per_layer) + cfg.padded_vocab
+        return F32 * per_token * int(np.prod(data["tokens"].shape[1:]))
 
-    def fleet_evaluate(self, params_b, test):
-        return jax.vmap(self._accuracy)(
-            params_b, test["tokens"], test["labels"], test["mask"]
-        )
+    def fleet_local_train(self, ops, params_b, train, lr, epochs, head, *, max_epochs):
+        delta, loss, routed = self._scan_train(
+            ops, params_b, train["tokens"], train["labels"], train["mask"], lr, epochs, head,
+            max_epochs=max_epochs)
+        return delta, loss, {"routed": routed}
 
-    def fleet_feedback(self, params_b, train, num_classes):
-        return jax.vmap(
-            functools.partial(self._distributions, num_classes=num_classes)
-        )(params_b, train["tokens"], train["mask"])
+    def fleet_evaluate(self, ops, params_b, test):
+        return self._accuracy(ops, params_b, test["tokens"], test["labels"], test["mask"])
+
+    def fleet_feedback(self, ops, params_b, train, num_classes):
+        return self._distributions(ops, params_b, train["tokens"], train["mask"], num_classes)
 
     # ---- per-client entry points (loop backend / SimClient) -------------
     def local_train(self, params, data, *, epochs, lr, head_only):
-        mask = jnp.ones((data.n,), jnp.float32)
+        one = jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], params)
         delta, loss = _client_train(
-            self, params, jnp.asarray(data.tokens_train), jnp.asarray(data.labels_train),
-            mask, jnp.asarray(lr, jnp.float32), jnp.asarray(epochs, jnp.int32),
-            jnp.asarray(1.0 if head_only else 0.0, jnp.float32), max_epochs=epochs,
+            self, self.operands, one, jnp.asarray(data.tokens_train)[None],
+            jnp.asarray(data.labels_train)[None], jnp.ones((1, data.n), jnp.float32),
+            jnp.full((1,), lr, jnp.float32), jnp.full((1,), epochs, jnp.int32),
+            jnp.full((1,), 1.0 if head_only else 0.0, jnp.float32), max_epochs=epochs,
         )
-        return delta, loss
+        return jax.tree_util.tree_map(lambda x: x[0], delta), loss[0]
 
     def evaluate(self, params, data):
+        one = jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], params)
         return float(_client_eval(
-            self, params, jnp.asarray(data.tokens_test), jnp.asarray(data.labels_test),
-            jnp.ones((len(data.tokens_test),), jnp.float32),
-        ))
+            self, self.operands, one, jnp.asarray(data.tokens_test)[None],
+            jnp.asarray(data.labels_test)[None], jnp.ones((1, len(data.tokens_test)), jnp.float32),
+        )[0])
 
     def feedback_inputs(self, params, data, num_classes):
+        one = jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], params)
         f_pred, s_soft = _client_feedback(
-            self, params, jnp.asarray(data.tokens_train),
-            jnp.ones((data.n,), jnp.float32), num_classes=num_classes,
+            self, self.operands, one, jnp.asarray(data.tokens_train)[None],
+            jnp.ones((1, data.n), jnp.float32), num_classes=num_classes,
         )
         f_true = data.label_histogram(num_classes)
-        return np.asarray(f_pred), f_true.astype(np.float32), np.asarray(s_soft)
+        return np.asarray(f_pred[0]), f_true.astype(np.float32), np.asarray(s_soft[0])
 
 
 @functools.partial(jax.jit, static_argnames=("task", "max_epochs"))
-def _client_train(task, delta, tokens, labels, mask, lr, epochs, head_frac, *,
+def _client_train(task, base, delta, tokens, labels, mask, lr, epochs, head_frac, *,
                   max_epochs: int):
-    return task._scan_train(delta, tokens, labels, mask, lr, epochs, head_frac,
-                            max_epochs=max_epochs)
+    delta, loss, _ = task._scan_train(base, delta, tokens, labels, mask, lr, epochs, head_frac,
+                                      max_epochs=max_epochs)
+    return delta, loss
 
 
 @functools.partial(jax.jit, static_argnames=("task",))
-def _client_eval(task, delta, tokens, labels, mask):
-    return task._accuracy(delta, tokens, labels, mask)
+def _client_eval(task, base, delta, tokens, labels, mask):
+    return task._accuracy(base, delta, tokens, labels, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("task", "num_classes"))
-def _client_feedback(task, delta, tokens, mask, *, num_classes: int):
-    return task._distributions(delta, tokens, mask, num_classes)
+def _client_feedback(task, base, delta, tokens, mask, *, num_classes: int):
+    return task._distributions(base, delta, tokens, mask, num_classes)
 
 
 # ---------------------------------------------------------------------------
@@ -312,9 +410,7 @@ def default_lm_task() -> LMTask:
     recompile the fleet launches."""
     global _DEFAULT_LM_TASK
     if _DEFAULT_LM_TASK is None:
-        cfg = get_config("tiny_lm")
-        base = model_init_params(cfg, jax.random.PRNGKey(0))
-        _DEFAULT_LM_TASK = LMTask(base=FrozenBase(base), cfg=cfg)
+        _DEFAULT_LM_TASK = LMTask.build(get_config("tiny_lm"), 0)
     return _DEFAULT_LM_TASK
 
 

@@ -13,7 +13,10 @@ hard-coded the toy MLP in ``fl/fleet.py`` / ``core/client.py`` /
 
 Implementations must be hashable value objects (frozen dataclasses): the
 fleet's fused launches pass the task as a static jit argument, so a task's
-identity keys the compile cache the same way the flatten spec does.
+identity keys the compile cache the same way the flatten spec does. Arrays
+a task computes with but never trains (a frozen base) are its
+``operands``: the fleet hands them to every launch as an argument, so they
+are never folded into a compiled program as constants.
 
 Two tasks ship:
 
@@ -49,11 +52,13 @@ class FleetData:
     ``(clients, ...)`` arrays whose layout only the owning task interprets
     (the fleet gathers rows by client index inside its launches and passes
     the dict through); ``f_true`` is the (clients, J) matrix of true label
-    histograms feeding the chi2 kernels."""
+    histograms feeding the chi2 kernels; ``tokens``, where the task trains
+    on tokens, each client's tokens per epoch."""
 
     train: dict[str, jax.Array]
     test: dict[str, jax.Array]
     f_true: np.ndarray
+    tokens: np.ndarray | None = None
 
 
 def pad_rows(arr: np.ndarray, n: int) -> np.ndarray:
@@ -78,23 +83,38 @@ class PersonalizationTask(Protocol):
     # ---- model surface -------------------------------------------------
     def init_params(self, key: jax.Array) -> PyTree: ...
 
+    @property
+    def operands(self) -> PyTree:
+        """Device arrays every fleet launch takes as its ``ops`` argument."""
+        ...
+
     # ---- fleet engine (batched, jit-pure, task static) -----------------
     def build_fleet_data(
         self, datasets: list[Any], shard: Callable[[jax.Array], jax.Array],
         num_classes: int,
     ) -> FleetData: ...
 
+    def client_bytes(self, kind: str, data: dict[str, jax.Array]) -> int:
+        """Device bytes one client's share of a ``train``, ``eval`` or
+        ``feedback`` launch over ``data`` holds at its peak (the fleet
+        sizes its client blocks from it)."""
+        ...
+
     def fleet_local_train(
-        self, params_b: PyTree, train: dict[str, jax.Array], lr: jax.Array,
+        self, ops: PyTree, params_b: PyTree, train: dict[str, jax.Array], lr: jax.Array,
         epochs: jax.Array, head: jax.Array, *, max_epochs: int,
-    ) -> tuple[PyTree, jax.Array]: ...
+    ) -> tuple[PyTree, jax.Array, dict[str, jax.Array]]:
+        """(trained params, losses, per-client counters) for a batch of
+        clients; the counters (empty for a task that counts nothing) are
+        summed by the fleet after its fetch."""
+        ...
 
     def fleet_evaluate(
-        self, params_b: PyTree, test: dict[str, jax.Array]
+        self, ops: PyTree, params_b: PyTree, test: dict[str, jax.Array]
     ) -> jax.Array: ...
 
     def fleet_feedback(
-        self, params_b: PyTree, train: dict[str, jax.Array], num_classes: int
+        self, ops: PyTree, params_b: PyTree, train: dict[str, jax.Array], num_classes: int
     ) -> tuple[jax.Array, jax.Array]: ...
 
     # ---- per-client (loop backend / SimClient) -------------------------
@@ -124,6 +144,10 @@ class MLPTask:
 
         return init_mlp(cfg or PAPER_TASKS["image_recognition"], key)
 
+    @property
+    def operands(self):
+        return ()
+
     # ---- fleet engine --------------------------------------------------
     def build_fleet_data(self, datasets, shard, num_classes):
         n_tr = max(len(d.y_train) for d in datasets)
@@ -149,20 +173,25 @@ class MLPTask:
         ])
         return FleetData(train=train, test=test, f_true=f_true)
 
-    def fleet_local_train(self, params_b, train, lr, epochs, head, *, max_epochs):
+    def client_bytes(self, kind, data):
+        """Each sample's features and the widest activation: kilobytes."""
+        return 4 * 8 * int(np.prod(data["x"].shape[1:]))
+
+    def fleet_local_train(self, ops, params_b, train, lr, epochs, head, *, max_epochs):
         from repro.models import mlp
 
-        return mlp.fleet_local_train(
+        params, losses = mlp.fleet_local_train(
             params_b, train["x"], train["y"], train["mask"], lr, epochs, head,
             max_epochs=max_epochs,
         )
+        return params, losses, {}
 
-    def fleet_evaluate(self, params_b, test):
+    def fleet_evaluate(self, ops, params_b, test):
         from repro.models import mlp
 
         return mlp.fleet_evaluate(params_b, test["x"], test["y"], test["mask"])
 
-    def fleet_feedback(self, params_b, train, num_classes):
+    def fleet_feedback(self, ops, params_b, train, num_classes):
         from repro.models import mlp
 
         return mlp.fleet_predict_distributions(

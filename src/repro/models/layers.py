@@ -9,6 +9,7 @@ softmax/gating/normalizers in float32.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -27,6 +28,99 @@ PyTree = Any
 def _dense_init(key, shape, in_axis_size, dtype):
     scale = 1.0 / math.sqrt(max(in_axis_size, 1))
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+# Base matmuls of a narrow-weight model under float32 activations (a bf16
+# base personalized by float32 adapters): the operands go in as the weight's
+# dtype and the products accumulate in float32, in the forward pass and in
+# the backward pass alike (the cotangent rounds to the weight's dtype as it
+# enters each transposed product; the weight's gradient is returned in its
+# dtype). One pass of the narrow operands is exact: the products ask for no
+# more precision, whatever the default matmul precision (a grouped
+# product's TPU kernel refuses more for bf16 operands).
+_ONE_PASS = jax.lax.Precision.DEFAULT
+
+
+def _one_pass(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    return jnp.einsum(eq, a, b, precision=_ONE_PASS, preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _mixed_einsum(eq, x, w):
+    return _one_pass(eq, x.astype(w.dtype), w)
+
+
+def _mixed_einsum_fwd(eq, x, w):
+    return _mixed_einsum(eq, x, w), (x.astype(w.dtype), w)
+
+
+def _mixed_einsum_bwd(eq, res, g):
+    xn, w = res
+    ins, out = eq.split("->")
+    xs, ws = ins.split(",")
+    gn = g.astype(w.dtype)
+    return _one_pass(f"{out},{ws}->{xs}", gn, w), _one_pass(f"{xs},{out}->{ws}", xn, gn).astype(w.dtype)
+
+
+_mixed_einsum.defvjp(_mixed_einsum_fwd, _mixed_einsum_bwd)
+
+# the weight gradient of a grouped product: each group's rows contracted
+_GROUPED_W = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])), lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+@jax.custom_vjp
+def _mixed_grouped(x, w, sizes):
+    return jax.lax.ragged_dot(x.astype(w.dtype), w, sizes, precision=_ONE_PASS, preferred_element_type=jnp.float32)
+
+
+def _mixed_grouped_fwd(x, w, sizes):
+    return _mixed_grouped(x, w, sizes), (x.astype(w.dtype), w, sizes)
+
+
+def _mixed_grouped_bwd(res, g):
+    xn, w, sizes = res
+    gn = g.astype(w.dtype)
+    dx = jax.lax.ragged_dot(gn, jnp.swapaxes(w, 1, 2), sizes, precision=_ONE_PASS,
+                            preferred_element_type=jnp.float32)
+    dw = jax.lax.ragged_dot_general(xn, gn, sizes, _GROUPED_W, precision=_ONE_PASS,
+                                    preferred_element_type=jnp.float32)
+    return dx, dw.astype(w.dtype), None
+
+
+_mixed_grouped.defvjp(_mixed_grouped_fwd, _mixed_grouped_bwd)
+
+
+def _mixed(x: jax.Array, w: jax.Array) -> bool:
+    return x.dtype == jnp.float32 and w.dtype != x.dtype
+
+
+def _dot(eq: str, x: jax.Array, w: jax.Array) -> jax.Array:
+    """``jnp.einsum(eq, x, w)`` for ``x`` times a base weight ``w``; a
+    narrower weight under float32 activations multiplies in the weight's
+    dtype and accumulates in float32."""
+    return _mixed_einsum(eq, x, w) if _mixed(x, w) else jnp.einsum(eq, x, w)
+
+
+def _grouped(x: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
+    """Grouped product ``ragged_dot(x, w, sizes)``: rows of ``x`` sorted by
+    group, ``sizes`` rows to each of ``w``'s groups; mixed precision as
+    :func:`_dot`."""
+    return _mixed_grouped(x, w, sizes) if _mixed(x, w) else jax.lax.ragged_dot(x, w, sizes)
+
+
+def lora(x: jax.Array, ad: PyTree, scale: float) -> jax.Array:
+    """A LoRA adapter on activations, ``scale * (x @ a) @ b``, never merged
+    into the weight. ``a`` is (d, r) and ``b`` (r, k); with a leading client
+    axis on both, ``x``'s leading (batch) axis is client-major, so every
+    client's rows meet that client's factors while the base matmul beside
+    it stays one product over all of them."""
+    a, b = ad["a"], ad["b"]
+    if a.ndim == 2:
+        return scale * ((x @ a) @ b)
+    xc = x.reshape(a.shape[0], -1, x.shape[-1])
+    y = jnp.einsum("cnr,crk->cnk", jnp.einsum("cnd,cdr->cnr", xc, a), b)
+    return scale * y.reshape(*x.shape[:-1], b.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -50,14 +144,68 @@ def rms_norm(params: PyTree, x: jax.Array, eps: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd) rotated pairwise; positions: (..., S)."""
+# YaRN, as DeepSeek-V2's reference code (DeepseekV2YarnRotaryEmbedding)
+# defines it for a rope part of ``dim`` dims, base theta, scaling factor s
+# and L original positions:
+#   f_extra(i) = theta^(-2i/dim), f_inter(i) = f_extra(i) / s, i < dim/2;
+#   c(r) = dim * ln(L / (2 pi r)) / (2 ln theta), the dim whose wavelength
+#   turns r times in L positions; lo = floor(c(beta_fast)),
+#   hi = ceil(c(beta_slow)), clipped to [0, dim - 1];
+#   m(i) = 1 - clamp((i - lo) / (hi - lo), 0, 1) (hi += 0.001 when equal);
+#   inv_freq = f_inter * (1 - m) + f_extra * m: dims that turn often keep
+#   their frequency, slow ones are interpolated by s;
+#   g(s, mu) = 0.1 mu ln s + 1 (1 when s <= 1); cos and sin are scaled by
+#   g(s, mscale) / g(s, mscale_all_dim) and the softmax scale by
+#   g(s, mscale_all_dim)^2.
+# DeepSeek-V2-Lite (dim 64, theta 10,000, s 40, L 4,096, beta 32 / 1,
+# mscale 0.707 / 0.707): lo = floor(10.47) = 10, hi = ceil(22.50) = 23,
+# cos/sin scale 1, softmax scale 192^-0.5 * 1.2608^2 = 0.11472.
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_range(dim: int, theta: float, scaling) -> tuple[int, int]:
+    def c(rotations):
+        return dim * math.log(scaling.original_max_position / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    return max(math.floor(c(scaling.beta_fast)), 0), min(math.ceil(c(scaling.beta_slow)), dim - 1)
+
+
+def rope_inv_freq(dim: int, theta: float, scaling=None) -> jax.Array:
+    """(dim/2,) rotation frequencies, YaRN-scaled when ``scaling`` is set."""
+    half = dim // 2
+    f_extra = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        return f_extra
+    lo, hi = yarn_range(dim, theta, scaling)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo) / (hi - lo if hi > lo else 0.001), 0.0, 1.0)
+    m = 1.0 - ramp
+    return f_extra / scaling.factor * (1.0 - m) + f_extra * m
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float, scaling=None) -> jax.Array:
+    """x: (..., S, H, hd) rotated pairwise; positions: (..., S); YaRN-scaled
+    when ``scaling`` (a ``YarnSpec``) is set."""
     hd = x.shape[-1]
     half = hd // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq = rope_inv_freq(hd, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freq  # (..., S, half)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        g = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+        cos, sin = cos * g, sin * g
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -167,16 +315,23 @@ def apply_attention(
     cache: PyTree | None = None,
     pos0: jax.Array | int = 0,
     return_cache: bool = False,
+    adapter: PyTree | None = None,
+    lora_scale: float = 1.0,
 ):
     """Returns (out, new_cache). Cache layout:
       standard: {"k": (B, S_ctx, KV, hd), "v": ...}
       MLA:      {"ckv": (B, S_ctx, lora), "krope": (B, S_ctx, rope_dim)}
-    In decode mode (cache is not None) S is the new-token count (1)."""
+    In decode mode (cache is not None) S is the new-token count (1).
+    ``adapter`` maps projection names (``wq``; MLA's ``w_dkv``) to LoRA
+    factors added to that projection's output (:func:`lora`)."""
     if cfg.mla is not None:
-        return _apply_mla(params, x, cfg, cache=cache, pos0=pos0, return_cache=return_cache)
+        return _apply_mla(params, x, cfg, cache=cache, pos0=pos0, return_cache=return_cache,
+                          adapter=adapter, lora_scale=lora_scale)
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    if adapter is not None and "wq" in adapter:
+        q = q + lora(x, adapter["wq"], lora_scale).reshape(q.shape).astype(q.dtype)
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
     positions = pos0 + jnp.arange(S)
@@ -207,43 +362,48 @@ def apply_attention(
     return out, (new_entries if return_cache else None)
 
 
-def _apply_mla(params, x, cfg: ModelConfig, *, cache, pos0, return_cache):
+def _apply_mla(params, x, cfg: ModelConfig, *, cache, pos0, return_cache, adapter=None, lora_scale=1.0):
     """DeepSeek-V2 multi-head latent attention. The cache holds only the
     compressed latent (kv_lora_rank) + shared rope key — the arch's whole
-    point: 512+64 dims instead of 2*16*192 per token."""
+    point: 512+64 dims instead of 2*16*192 per token. The rope part is
+    YaRN-scaled where the config says so; ``adapter`` may carry LoRA factors
+    for ``wq`` and the latent down-projection ``w_dkv``."""
     m = cfg.mla
-    B, S, _ = x.shape
-    H = cfg.num_heads
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])  # (B,S,H,nope+rope)
-    q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    positions = pos0 + jnp.arange(S)
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    adapter = adapter or {}
+    with jax.named_scope("mla"):
+        positions = pos0 + jnp.arange(x.shape[1])
+        q = _dot("bsd,dhk->bshk", x, params["wq"])  # (B,S,H,nope+rope)
+        if "wq" in adapter:
+            q = q + lora(x, adapter["wq"], lora_scale).reshape(q.shape)
+        q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
+        q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
-    dkv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])  # (B,S,lora+rope)
-    ckv, k_rope = dkv[..., : m.kv_lora_rank], dkv[..., m.kv_lora_rank :]
-    ckv = rms_norm(params["kv_norm"], ckv, cfg.norm_eps)
-    k_rope = rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
-    new_entries = {"ckv": ckv, "krope": k_rope}
-    if cache is not None:
-        ckv = jnp.concatenate([cache["ckv"], ckv], axis=1)
-        k_rope = jnp.concatenate([cache["krope"], k_rope], axis=1)
+        dkv = _dot("bsd,dr->bsr", x, params["w_dkv"])  # (B,S,lora+rope)
+        if "w_dkv" in adapter:
+            dkv = dkv + lora(x, adapter["w_dkv"], lora_scale)
+        ckv, k_rope = dkv[..., : m.kv_lora_rank], dkv[..., m.kv_lora_rank :]
+        ckv = rms_norm(params["kv_norm"], ckv, cfg.norm_eps)
+        k_rope = rope(k_rope[:, :, None, :], positions, cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
+        new_entries = {"ckv": ckv, "krope": k_rope}
+        if cache is not None:
+            ckv = jnp.concatenate([cache["ckv"], ckv], axis=1)
+            k_rope = jnp.concatenate([cache["krope"], k_rope], axis=1)
 
-    ukv = jnp.einsum("bsr,rhk->bshk", ckv, params["w_ukv"])
-    k_nope = ukv[..., : m.qk_nope_head_dim]
-    v = ukv[..., m.qk_nope_head_dim :]
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (*k_nope.shape[:3], m.qk_rope_head_dim))],
-        axis=-1,
-    )
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    from repro.kernels import ops as K
+        ukv = _dot("bsr,rhk->bshk", ckv, params["w_ukv"])
+        k_nope = ukv[..., : m.qk_nope_head_dim]
+        v = ukv[..., m.qk_nope_head_dim :]
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (*k_nope.shape[:3], m.qk_rope_head_dim))],
+            axis=-1,
+        )
+        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+        from repro.kernels import ops as K
 
-    out = K.attention(
-        jnp.swapaxes(q_full, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-        causal=cfg.causal, scale=scale, q_pos0=pos0,
-    )
-    out = jnp.einsum("bhsk,hkd->bsd", out, params["wo"])
+        out = K.attention(
+            jnp.swapaxes(q_full, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+            causal=cfg.causal, scale=mla_softmax_scale(cfg), q_pos0=pos0,
+        )
+        out = _dot("bhsk,hkd->bsd", out, params["wo"])
     return out, (new_entries if return_cache else None)
 
 
@@ -264,18 +424,18 @@ def init_dense_ffn(key, d: int, d_ff: int, dtype) -> PyTree:
 def apply_dense_ffn(params: PyTree, x: jax.Array) -> jax.Array:
     from repro.models import dist
 
-    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, params["wg"]))
-    up = dist.constrain(jnp.einsum("bsd,df->bsf", x, params["wu"]), "batch", None, "model")
+    gate = jax.nn.silu(_dot("bsd,df->bsf", x, params["wg"]))
+    up = dist.constrain(_dot("bsd,df->bsf", x, params["wu"]), "batch", None, "model")
     h = dist.constrain(gate * up, "batch", None, "model")
-    return dist.constrain(jnp.einsum("bsf,fd->bsd", h, params["wd"]), "batch", None, None)
+    return dist.constrain(_dot("bsf,fd->bsd", h, params["wd"]), "batch", None, None)
 
 
 def init_moe_ffn(key, cfg: ModelConfig, dtype) -> PyTree:
     moe = cfg.moe
-    d, de, E = cfg.d_model, moe.d_expert, moe.num_experts
+    d, de, E = cfg.d_model, moe.d_expert, moe.num_held  # the experts held here
     ks = jax.random.split(key, 5)
     p = {
-        "router": _dense_init(ks[0], (d, E), d, jnp.float32),
+        "router": _dense_init(ks[0], (d, moe.num_experts), d, jnp.float32),
         "wg": _dense_init(ks[1], (E, d, de), d, dtype),
         "wu": _dense_init(ks[2], (E, d, de), d, dtype),
         "wd": _dense_init(ks[3], (E, de, d), de, dtype),
@@ -285,38 +445,116 @@ def init_moe_ffn(key, cfg: ModelConfig, dtype) -> PyTree:
     return p
 
 
+def _gate(params, xt, moe):
+    """The router over all ``num_experts``: float32 logits and softmax,
+    greedy top-k, weights renormalized only where ``norm_topk_prob`` says so,
+    then scaled by ``routed_scaling_factor``. Returns (probs, top-k weights,
+    top-k expert ids)."""
+    logits = jnp.einsum("td,de->te", xt, params["router"].astype(xt.dtype)).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topw, topi = jax.lax.top_k(probs, moe.top_k)
+    if moe.norm_topk_prob:
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-9)
+    return probs, topw * moe.routed_scaling_factor, topi
+
+
+def _balance_loss(probs, topi, E: int, K: int, valid=None) -> jax.Array:
+    """Switch load-balancing loss, E * sum_e f_e * p_e / K, over all experts,
+    per group (the leading axes of ``probs`` but the last two) of the tokens
+    ``valid`` marks, averaged over groups."""
+    density = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.float32), axis=-2)
+    if valid is None:
+        f, p = jnp.mean(density, axis=-2), jnp.mean(probs, axis=-2)
+    else:
+        count = jnp.sum(valid, axis=-1, keepdims=True)
+        f = jnp.sum(density * valid[..., None], axis=-2) / count
+        p = jnp.sum(probs * valid[..., None], axis=-2) / count
+    return E * jnp.mean(jnp.sum(f * p, axis=-1)) / K
+
+
+def apply_moe_held(params: PyTree, x: jax.Array, cfg: ModelConfig):
+    """Dropless expert layer of one chip of an expert-parallel deployment.
+
+    The router scores all ``num_experts``; this layer holds the
+    ``num_held`` experts from ``held_first`` on (``params`` carries only
+    theirs) and computes their part of the result for every (token, k) pair
+    routed to them, none dropped: the pairs are sorted by expert and the
+    three expert matmuls run as grouped products over the held experts
+    (``ragged_dot``), scaled by the gate weight and scattered back onto their
+    tokens. The shared experts run for every token. What the absent experts
+    would add is left out; with every expert held this is the whole layer.
+
+    Returns (out, load-balancing loss, (B, S) int32 count of each token's
+    pairs routed to held experts)."""
+    moe = cfg.moe
+    B, S, D = x.shape
+    E, K, Eh, first = moe.num_experts, moe.top_k, moe.num_held, moe.held_first
+    xt = x.reshape(B * S, D)
+    T = xt.shape[0]
+    with jax.named_scope("moe"):
+        probs, topw, topi = _gate(params, xt, moe)
+        local = topi - first
+        held = (local >= 0) & (local < Eh)
+        key = jnp.where(held, local, Eh).reshape(-1)  # not held: sorts last
+        # every held pair fits: a token's K experts are distinct
+        n = T * min(K, Eh)
+        order = jnp.argsort(key, stable=True)[:n]
+        tok = order // K
+        live = key[order] < Eh
+        w = jnp.where(live, topw.reshape(-1)[order], 0.0)
+        sizes = jnp.sum(jax.nn.one_hot(key, Eh, dtype=jnp.int32), axis=0)
+        # rows past the groups (pairs not held) are left undefined by a
+        # grouped product on some backends: select them away before and
+        # after each product, so that neither they nor their gradients
+        # reach a token
+        xs = jnp.where(live[:, None], xt[tok], 0.0)
+        h = jax.nn.silu(_grouped(xs, params["wg"], sizes)) * _grouped(xs, params["wu"], sizes)
+        h = jnp.where(live[:, None], h, 0.0)
+        y = _grouped(h, params["wd"], sizes)
+        y = jnp.where(live[:, None], y * w[:, None].astype(y.dtype), 0.0)
+        out = jnp.zeros((T, D), y.dtype).at[tok].add(y).reshape(B, S, D)
+        if moe.num_shared:
+            out = out + apply_dense_ffn(params["shared"], x)
+    routed = jnp.sum(held, axis=-1, dtype=jnp.int32).reshape(B, S)
+    return out, _balance_loss(probs, topi, E, K), routed
+
+
 def apply_moe_ffn(
     params: PyTree,
     x: jax.Array,  # (B, S, D)
     cfg: ModelConfig,
     capacity_factor: float = 1.25,
     group_size: int = 4096,
-) -> tuple[jax.Array, jax.Array]:
-    """GShard-style top-k dispatch MoE (expert-parallel friendly).
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """GShard-style top-k dispatch MoE (expert-parallel friendly), or the
+    dropless :func:`apply_moe_held` under ``cfg.moe_dropless``.
 
     Tokens are processed in groups; within a group each token routes to its
     top-k experts subject to per-expert capacity C = ceil(k*G*cf/E); overflow
-    tokens fall through (residual connection carries them). Returns
-    (out, aux_loss) where aux_loss is the standard load-balancing loss.
+    tokens fall through (residual connection carries them). The last group
+    is padded with zero tokens, which queue behind every real token and so
+    never take a real token's slot. Returns (out, aux_loss, routed): the
+    standard load-balancing loss and each token's count of dispatched pairs.
     """
+    if cfg.moe_dropless:
+        return apply_moe_held(params, x, cfg)
     moe = cfg.moe
+    if moe.num_held != moe.num_experts:
+        raise ValueError("capacity dispatch holds every expert; a held share needs moe_dropless")
     B, S, D = x.shape
     E, K = moe.num_experts, moe.top_k
     T = B * S
-    xt = x.reshape(T, D)
     g = min(group_size, T)
-    n_groups = T // g
-    xg = xt[: n_groups * g].reshape(n_groups, g, D)
+    n_groups = -(-T // g)
+    xt = x.reshape(T, D)
+    if n_groups * g > T:
+        xt = jnp.concatenate([xt, jnp.zeros((n_groups * g - T, D), xt.dtype)])
+    xg = xt.reshape(n_groups, g, D)
 
-    logits = jnp.einsum("ngd,de->nge", xg, params["router"].astype(xg.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # (n, g, E)
-    topw, topi = jax.lax.top_k(probs, K)  # (n, g, K)
-    topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-9)
+    probs, topw, topi = _gate(params, xt, moe)
+    probs, topw, topi = (a.reshape(n_groups, g, -1) for a in (probs, topw, topi))
 
-    if cfg.moe_dropless:
-        C = g  # every token always fits its experts (serving/consistency mode)
-    else:
-        C = max(1, int(math.ceil(K * g * capacity_factor / E)))
+    C = max(1, int(math.ceil(K * g * capacity_factor / E)))
     # position of each (token, k) within its expert queue
     onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)  # (n, g, K, E)
     flat = onehot.reshape(n_groups, g * K, E)
@@ -333,19 +571,15 @@ def apply_moe_ffn(
     expert_out = jnp.einsum("necf,efd->necd", h_g * h_u, params["wd"])
     out = jnp.einsum("ngec,necd->ngd", combine.astype(expert_out.dtype), expert_out)
 
-    # load-balancing aux loss (Switch): E * sum_e f_e * p_e
-    density = jnp.mean(jnp.sum(onehot, axis=2), axis=1)  # (n, E) token fraction
-    router_prob = jnp.mean(probs, axis=1)  # (n, E)
-    aux = E * jnp.mean(jnp.sum(density * router_prob, axis=-1)) / K
-
-    out_flat = out.reshape(n_groups * g, D)
-    if n_groups * g < T:  # ragged tail routes dense through top-1 expert 0 path: rare; pad path
-        tail = jnp.zeros((T - n_groups * g, D), out_flat.dtype)
-        out_flat = jnp.concatenate([out_flat, tail], axis=0)
-    y = out_flat.reshape(B, S, D)
+    valid = None
+    if n_groups * g > T:
+        valid = (jnp.arange(n_groups * g) < T).astype(jnp.float32).reshape(n_groups, g)
+    aux = _balance_loss(probs, topi, E, K, valid)
+    routed = jnp.sum(keep, axis=(-2, -1)).reshape(-1)[:T].astype(jnp.int32).reshape(B, S)
+    y = out.reshape(n_groups * g, D)[:T].reshape(B, S, D)
     if moe.num_shared:
         y = y + apply_dense_ffn(params["shared"], x)
-    return y, aux
+    return y, aux, routed
 
 
 # ---------------------------------------------------------------------------

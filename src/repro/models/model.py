@@ -147,7 +147,8 @@ def init_cache(cfg: ModelConfig, batch: int, ctx_len: int, dtype=jnp.float32, ma
 
 
 def _apply_layer(
-    lp: PyTree, spec: LayerSpec, cfg: ModelConfig, x, *, cache, pos0, decode, collect=False
+    lp: PyTree, spec: LayerSpec, cfg: ModelConfig, x, *, cache, pos0, decode, collect=False,
+    adapter=None, lora_scale=1.0,
 ):
     h = L.rms_norm(lp["norm1"], x, cfg.norm_eps)
     if spec.mixer in ("attn", "attn_local"):
@@ -156,7 +157,7 @@ def _apply_layer(
         else:
             mix, new_cache = L.apply_attention(
                 lp["mixer"], h, cfg, local=spec.mixer == "attn_local", pos0=pos0,
-                return_cache=collect,
+                return_cache=collect, adapter=adapter, lora_scale=lora_scale,
             )
     elif spec.mixer == "mamba":
         mix, new_cache = L.apply_mamba(lp["mixer"], h, cfg, cache=cache)
@@ -175,16 +176,17 @@ def _apply_layer(
     # full-d_ff partial sums + all-reduce (§Perf iteration 2)
     x = dist.constrain(x + mix, "batch", None, None)
     aux = jnp.zeros((), jnp.float32)
+    routed = jnp.zeros(x.shape[:2], jnp.int32)
     if spec.ffn != "none":
         h2 = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
         if spec.ffn == "dense":
             f = L.apply_dense_ffn(lp["ffn"], h2)
         else:
-            f, aux = L.apply_moe_ffn(lp["ffn"], h2, cfg)
+            f, aux, routed = L.apply_moe_ffn(lp["ffn"], h2, cfg)
         if cfg.use_post_norm:
             f = L.rms_norm(lp["post_norm2"], f, cfg.norm_eps)
         x = dist.constrain(x + f, "batch", None, None)
-    return x, new_cache, aux
+    return x, new_cache, (aux, routed)
 
 
 def _attn_decode(mp, h, cfg: ModelConfig, local: bool, cache, pos0):
@@ -222,12 +224,12 @@ def _mla_decode_absorbed(mp, h, cfg: ModelConfig, cache, pos0):
     q = jnp.einsum("bsd,dhk->bshk", h, mp["wq"])  # (B,1,H,nope+rope)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
     positions = pos0 + jnp.arange(1)
-    q_rope = L.rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     dkv = jnp.einsum("bsd,dr->bsr", h, mp["w_dkv"])
     ckv_new, krope_new = dkv[..., : m.kv_lora_rank], dkv[..., m.kv_lora_rank :]
     ckv_new = L.rms_norm(mp["kv_norm"], ckv_new, cfg.norm_eps)
-    krope_new = L.rope(krope_new[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    krope_new = L.rope(krope_new[:, :, None, :], positions, cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     ckv_buf = jax.lax.dynamic_update_slice(cache["ckv"], ckv_new.astype(cache["ckv"].dtype), (0, pos0, 0))
     krope_buf = jax.lax.dynamic_update_slice(
         cache["krope"], krope_new.astype(cache["krope"].dtype), (0, pos0, 0)
@@ -238,8 +240,7 @@ def _mla_decode_absorbed(mp, h, cfg: ModelConfig, cache, pos0):
     q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, w_uk)  # absorb: q in latent space
     s_nope = jnp.einsum("bshr,btr->bhst", q_abs, ckv_buf.astype(q_abs.dtype))
     s_rope = jnp.einsum("bshk,btk->bhst", q_rope, krope_buf.astype(q_rope.dtype))
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    s = (s_nope + s_rope).astype(jnp.float32) * scale
+    s = (s_nope + s_rope).astype(jnp.float32) * L.mla_softmax_scale(cfg)
     t_pos = jnp.arange(ckv_buf.shape[1])
     s = jnp.where((t_pos <= pos0)[None, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
@@ -264,17 +265,31 @@ def forward(
     batch: dict[str, jax.Array],
     cache: PyTree | None = None,
     return_cache: bool = False,
-) -> tuple[jax.Array, jax.Array, PyTree | None]:
-    """Returns (logits, moe_aux_loss, new_cache).
+    *,
+    adapters: PyTree | None = None,
+    lora_scale: float = 1.0,
+    routed: bool = False,
+):
+    """Returns (logits, moe_aux_loss, new_cache), and with ``routed`` also
+    the (B, S) count of each position's (token, expert) pairs the expert
+    layers computed.
 
     batch: {"tokens": (B,S) int32} or {"embeds": (B,S,D)} for frontend-stub
     archs. Decode mode iff ``cache`` is not None (then S == 1 and the new
     token goes to buffer slot ``cache["len"]``). ``return_cache=True`` in
     full-sequence mode collects prefill caches (exact-length buffers).
+
+    ``adapters`` adds LoRA factors on activations (``layers.lora``, scaled
+    by ``lora_scale``), never merged into the weights: ``prefix`` (a list of
+    per-layer attention adapters, None for a layer without), ``blocks``
+    (per-slot adapters stacked over periods, like ``params["blocks"]``) and
+    ``head`` (on the logits); a leading client axis on the factors makes the
+    batch client-major (``layers.lora``). Full-sequence mode only.
     """
     decode = cache is not None
     collect = decode or return_cache
     pos0 = cache["len"] if decode else 0
+    adapters = adapters or {}
     if "tokens" in batch:
         x = jnp.take(params["embed"], batch["tokens"], axis=0)
     else:
@@ -285,48 +300,57 @@ def forward(
         x = x + _sinusoidal(x.shape[1], cfg.d_model, x.dtype)[None]
 
     aux_total = jnp.zeros((), jnp.float32)
+    routed_total = jnp.zeros(x.shape[:2], jnp.int32)
     new_cache: dict[str, Any] = {"len": pos0 + x.shape[1]} if collect else None
 
+    prefix_ad = adapters.get("prefix") or [None] * len(cfg.prefix)
     for i, spec in enumerate(cfg.prefix):
         c_i = cache["prefix"][i] if decode else None
-        x, nc, aux = _apply_layer(
-            params["prefix"][i], spec, cfg, x, cache=c_i, pos0=pos0, decode=decode, collect=collect
+        x, nc, (aux, r) = _apply_layer(
+            params["prefix"][i], spec, cfg, x, cache=c_i, pos0=pos0, decode=decode, collect=collect,
+            adapter=prefix_ad[i], lora_scale=lora_scale,
         )
         aux_total += aux
+        routed_total += r
         if collect:
             new_cache.setdefault("prefix", []).append(nc)
 
     if cfg.num_periods:
+        block_ad = adapters.get("blocks")
+
         def period_fn(carry, xs):
-            x_c, aux_c = carry
-            if decode:
-                lp, lc = xs
-            else:
-                lp, lc = xs, {}
+            x_c, aux_c, routed_c = carry
+            lp, lc, la = xs
             ncs = {}
             for i, spec in enumerate(cfg.pattern):
-                x_c, nc, aux = _apply_layer(
+                x_c, nc, (aux, r) = _apply_layer(
                     lp[f"slot{i}"], spec, cfg, x_c,
                     cache=lc.get(f"slot{i}"), pos0=pos0, decode=decode, collect=collect,
+                    adapter=la.get(f"slot{i}"), lora_scale=lora_scale,
                 )
                 aux_c += aux
+                routed_c += r
                 ncs[f"slot{i}"] = nc if nc is not None else 0
-            return (x_c, aux_c), (ncs if collect else 0)
+            return (x_c, aux_c, routed_c), (ncs if collect else 0)
 
         body = period_fn
         if cfg.train.remat and not decode and not collect:
             body = jax.checkpoint(period_fn, prevent_cse=False)
-        xs = (params["blocks"], cache["blocks"]) if decode else params["blocks"]
-        (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), xs)
+        xs = (params["blocks"], cache["blocks"] if decode else {}, block_ad or {})
+        (x, aux_total, routed_total), ys = jax.lax.scan(body, (x, aux_total, routed_total), xs)
         if collect:
             new_cache["blocks"] = ys
 
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        logits = L._dot("bsd,vd->bsv", x, params["embed"])
     else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+        logits = L._dot("bsd,dv->bsv", x, params["lm_head"])
+    if "head" in adapters:
+        logits = logits + L.lora(x, adapters["head"], lora_scale).astype(logits.dtype)
     if cfg.final_logit_softcap is not None:
         cap = cfg.final_logit_softcap
         logits = cap * jnp.tanh(logits / cap)
+    if routed:
+        return logits, aux_total, new_cache, routed_total
     return logits, aux_total, new_cache

@@ -118,3 +118,24 @@ def test_flash_attention_fwd_bwd(spec, case):
     loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32))
     _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
+
+
+def test_expert_share_fwd_bwd_at_deepseek_widths(spec):
+    """DeepSeek-V2-Lite's expert layer as one chip of eight holds it (8 of 64
+    routed experts of width 1,408, 2 shared, hidden 2,048), 1,024 tokens,
+    bf16 weights under float32 activations and the configurations'
+    ``highest`` matmul precision: its grouped products, forward and input
+    gradient (a bf16 grouped product asked for more than one pass is
+    refused by the chip's compiler)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    cfg = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, held=8), moe_dropless=True)
+    params = jax.eval_shape(lambda: L.init_moe_ffn(jax.random.PRNGKey(0), cfg, jnp.bfloat16))
+    params = jax.tree_util.tree_map(lambda p: spec(p.shape, p.dtype), params)
+    loss = lambda x, p: jnp.sum(L.apply_moe_held(p, x, cfg)[0])  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        _compile(jax.grad(loss), spec((4, 256, cfg.d_model)), params)
