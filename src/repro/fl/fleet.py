@@ -422,9 +422,12 @@ class ClientFleet:
 
     @staticmethod
     def _record_counts(counts: dict) -> None:
-        """The launch's fetched counters as zero-length spans."""
+        """The launch's fetched counters as zero-length spans: the pairs its
+        forward passes routed to held experts, and its expert layer calls,
+        those that ran the smallest pair buffer and the buffers' rows."""
         if "routed" in counts:
-            with span("train/routed", pairs=int(np.sum(counts["routed"]))):
+            with span("train/routed", pairs=int(np.sum(counts["routed"])),
+                      **{k: int(np.sum(counts[k])) for k in ("calls", "small", "rows")}):
                 pass
 
     def train_cohort(

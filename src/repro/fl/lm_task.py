@@ -201,9 +201,10 @@ class LMTask:
 
     # ---- per-client arithmetic, batched over clients -------------------
     def _forward(self, base, delta, tokens):
-        """Logits (C, n, S, V) float32 and each position's count of pairs
-        routed to held experts (C, n, S) for the clients' sequences
-        ``tokens`` (C, n, S)."""
+        """Logits (C, n, S, V) float32 and the expert layers' counts
+        (``model.forward``'s) for the clients' sequences ``tokens``
+        (C, n, S), each position's count of pairs routed to held experts
+        as (C, n, S)."""
         C, n, S = tokens.shape
         embeds = jnp.take(base["embed"], tokens, axis=0).astype(jnp.float32)  # (C, n, S, d)
         if self.cfg.tie_embeddings:
@@ -212,22 +213,23 @@ class LMTask:
             b_tok = jax.vmap(lambda b, t: b[:, t])(delta["head_b"], tokens)  # (C, r, n, S)
             embeds = embeds + self.lora_scale * jnp.einsum("crns,cdr->cnsd", b_tok, delta["head_a"])
         embeds = embeds.reshape(C * n, S, -1)
-        logits, _, _, routed = model_forward(
+        logits, _, _, routing = model_forward(
             self.cfg, base, {"embeds": embeds}, adapters=self._adapters(delta),
             lora_scale=self.lora_scale, routed=True,
         )
-        return logits.astype(jnp.float32).reshape(C, n, S, -1), routed.reshape(C, n, S)
+        return (logits.astype(jnp.float32).reshape(C, n, S, -1),
+                {**routing, "pairs": routing["pairs"].reshape(C, n, S)})
 
     def _nll(self, base, delta, tokens, labels, seq_mask):
         """Per-client mean next-token NLL over valid sequences (padded rows
-        masked), and per-client routed pair counts."""
-        logits, routed = self._forward(base, delta, tokens)
+        masked), and the expert layers' counts with ``pairs`` per client."""
+        logits, routing = self._forward(base, delta, tokens)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         per = (lse - picked) * seq_mask[..., None]  # (C, n, S)
         denom = jnp.maximum(jnp.sum(seq_mask, axis=1) * tokens.shape[2], 1.0)
-        counts = jnp.sum(routed * seq_mask[..., None].astype(routed.dtype), axis=(1, 2))
-        return jnp.sum(per, axis=(1, 2)) / denom, counts
+        pairs = jnp.sum(routing["pairs"] * seq_mask[..., None].astype(jnp.int32), axis=(1, 2))
+        return jnp.sum(per, axis=(1, 2)) / denom, {**routing, "pairs": pairs}
 
     def _scan_train(self, base, delta, tokens, labels, seq_mask, lr, epochs, head_frac,
                     max_epochs: int):
@@ -236,16 +238,19 @@ class LMTask:
         it through untouched, and head-only fine-tuning selects the
         attention LoRA gradients to exact zeros (the head LoRA is the LM
         analogue of the MLP's last layer). Returns the deltas, the last
-        active epoch's losses and the pairs routed to held experts in the
-        active epochs' forward passes, per client."""
+        active epoch's losses and the expert layers' counts over the forward
+        passes of the active epochs: ``pairs`` routed to held experts per
+        client, and the launch's layer ``calls`` in epochs where any client
+        is active, those of them that ran the ``small``-est pair buffer and
+        the buffers' ``rows``."""
 
         def loss_fn(p):
-            nll, routed = self._nll(base, p, tokens, labels, seq_mask)
-            return jnp.sum(nll), (nll, routed)
+            nll, routing = self._nll(base, p, tokens, labels, seq_mask)
+            return jnp.sum(nll), (nll, routing)
 
         def step(carry, e):
-            p, last_loss, routed_sum = carry
-            (_, (loss, routed)), grads = jax.value_and_grad(loss_fn, has_aux=True)(p)
+            p, last_loss, counts = carry
+            (_, (loss, routing)), grads = jax.value_and_grad(loss_fn, has_aux=True)(p)
             freeze_body = head_frac > 0
             grads = {k: g if k in ("head_a", "head_b") else jax.tree_util.tree_map(
                 lambda x: jnp.where(_per_client(freeze_body, x), jnp.zeros_like(x), x), g)
@@ -253,13 +258,16 @@ class LMTask:
             active = e < epochs
             p2 = jax.tree_util.tree_map(
                 lambda a, g: jnp.where(_per_client(active, a), a - _per_client(lr, a) * g, a), p, grads)
-            return (p2, jnp.where(active, loss, last_loss),
-                    routed_sum + jnp.where(active, routed, 0)), None
+            counts = {k: v + jnp.where(active if k == "pairs" else jnp.any(active), routing[k], 0)
+                      for k, v in counts.items()}
+            return (p2, jnp.where(active, loss, last_loss), counts), None
 
         C = tokens.shape[0]
-        init = (delta, jnp.zeros((C,), jnp.float32), jnp.zeros((C,), jnp.int32))
-        (delta, loss, routed), _ = jax.lax.scan(step, init, jnp.arange(max_epochs))
-        return delta, loss, routed
+        zero = jnp.zeros((), jnp.int32)
+        counts = {"pairs": jnp.zeros((C,), jnp.int32), "calls": zero, "small": zero, "rows": zero}
+        (delta, loss, counts), _ = jax.lax.scan(step, (delta, jnp.zeros((C,), jnp.float32), counts),
+                                                jnp.arange(max_epochs))
+        return delta, loss, counts
 
     def _accuracy(self, base, delta, tokens, labels, seq_mask):
         logits, _ = self._forward(base, delta, tokens)
@@ -337,10 +345,14 @@ class LMTask:
         return F32 * per_token * int(np.prod(data["tokens"].shape[1:]))
 
     def fleet_local_train(self, ops, params_b, train, lr, epochs, head, *, max_epochs):
-        delta, loss, routed = self._scan_train(
+        delta, loss, counts = self._scan_train(
             ops, params_b, train["tokens"], train["labels"], train["mask"], lr, epochs, head,
             max_epochs=max_epochs)
-        return delta, loss, {"routed": routed}
+        # the launch-wide counts ride the first client's row: every block
+        # of the launch and the fleet's cut to its real clients keep it
+        first = jnp.arange(loss.shape[0]) == 0
+        return delta, loss, {"routed": counts.pop("pairs"),
+                             **{k: jnp.where(first, v, 0) for k, v in counts.items()}}
 
     def fleet_evaluate(self, ops, params_b, test):
         return self._accuracy(ops, params_b, test["tokens"], test["labels"], test["mask"])

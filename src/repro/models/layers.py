@@ -472,6 +472,103 @@ def _balance_loss(probs, topi, E: int, K: int, valid=None) -> jax.Array:
     return E * jnp.mean(jnp.sum(f * p, axis=-1)) / K
 
 
+# Grouped products tile the rows of their operands by this many; the pair
+# buffer's sizes below the top rung are rounded up to it.
+_ROW_TILE = 128
+
+
+def pair_ladder(T: int, moe) -> tuple[int, ...]:
+    """Static sizes of :func:`apply_moe_held`'s pair buffer for ``T`` tokens,
+    smallest first. The top rung, ``n = T * min(top_k, num_held)``, holds
+    every pair a token can route to the held experts. A quarter and a half
+    of it, rounded up to the row tile, are rungs below it where they hold at
+    least twice the pairs a uniform router sends to the held experts,
+    ``T * top_k * num_held / num_experts``. With every expert held every
+    pair is live, and ``n`` is the only rung."""
+    K, Eh, E = moe.top_k, moe.num_held, moe.num_experts
+    n = T * min(K, Eh)
+    room = 2 * T * K * Eh / E
+    below = {-(-n // (d * _ROW_TILE)) * _ROW_TILE for d in (4, 2)}
+    return tuple(sorted(r for r in below if room <= r < n)) + (n,)
+
+
+def _held_pairs(rows: int, K: int, experts, xt, topw, order, key, sizes):
+    """The held experts' part of the layer for ``xt`` (T, D) over the first
+    ``rows`` pairs of ``order`` (the flat (token, k) pairs sorted by held
+    expert, the pairs routed elsewhere last): gathered, run through the
+    grouped products, scaled by the gate weight and scattered back onto
+    their tokens."""
+    Eh = sizes.shape[0]
+    order = order[:rows]
+    tok = order // K
+    live = key[order] < Eh
+    w = jnp.where(live, topw[order], 0.0)
+    # rows past the groups (pairs not held) are left undefined by a grouped
+    # product on some backends: select them away before and after each
+    # product, so that neither they nor their gradients reach a token
+    xs = jnp.where(live[:, None], xt[tok], 0.0)
+    h = jax.nn.silu(_grouped(xs, experts["wg"], sizes)) * _grouped(xs, experts["wu"], sizes)
+    h = jnp.where(live[:, None], h, 0.0)
+    y = _grouped(h, experts["wd"], sizes)
+    y = jnp.where(live[:, None], y * w[:, None].astype(y.dtype), 0.0)
+    return jnp.zeros(xt.shape, y.dtype).at[tok].add(y)
+
+
+def _rung(ladder: tuple[int, ...], sizes: jax.Array) -> jax.Array:
+    """The smallest rung of ``ladder`` that holds the ``sizes.sum()`` held
+    pairs."""
+    return jnp.sum(jnp.sum(sizes) > jnp.asarray(ladder[:-1]), dtype=jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _laddered(ladder, K, experts, xt, topw, order, key, sizes):
+    """:func:`_held_pairs` over the smallest rung of ``ladder`` that holds the
+    held pairs, chosen on the device."""
+    return jax.lax.switch(_rung(ladder, sizes), [functools.partial(_held_pairs, r, K) for r in ladder],
+                          experts, xt, topw, order, key, sizes)
+
+
+# The backward pass chooses its rung again and recomputes the rung's pairs:
+# the residuals are the inputs, whatever the rung. (Differentiating the
+# conditional itself would give every rung the residuals of all of them,
+# the top rung's among them.)
+def _laddered_fwd(ladder, K, *args):
+    return _laddered(ladder, K, *args), args
+
+
+def _laddered_bwd(ladder, K, args, g):
+    experts, xt, topw, order, key, sizes = args
+    # the barrier hands the conditional the weights as they are; without it
+    # the TPU compiler lays out a transposed copy of the weights of every
+    # period at once, ahead of the loop over periods, for the conditional
+    experts = jax.lax.optimization_barrier(experts)
+
+    def rung(rows):
+        def pull(g, experts):
+            _, vjp = jax.vjp(lambda e, x, w: _held_pairs(rows, K, e, x, w, order, key, sizes), experts, xt, topw)
+            return vjp(g)
+        return pull
+
+    return (*jax.lax.switch(_rung(ladder, sizes), [rung(r) for r in ladder], g, experts), None, None, None)
+
+
+_laddered.defvjp(_laddered_fwd, _laddered_bwd)
+
+
+def _routing(pairs: jax.Array, rung: jax.Array, ladder: tuple[int, ...]) -> dict:
+    """An expert layer call's counts: each token's pairs computed (``pairs``),
+    the call itself (``calls``), whether it ran its smallest buffer
+    (``small``) and the buffer's rows (``rows``)."""
+    return {"pairs": pairs, "calls": jnp.ones((), jnp.int32), "small": (rung == 0).astype(jnp.int32),
+            "rows": jnp.asarray(ladder, jnp.int32)[rung]}
+
+
+def no_routing(shape: tuple[int, int]) -> dict:
+    """The counts of a layer without experts: :func:`_routing`'s, all zero."""
+    zero = jnp.zeros((), jnp.int32)
+    return {"pairs": jnp.zeros(shape, jnp.int32), "calls": zero, "small": zero, "rows": zero}
+
+
 def apply_moe_held(params: PyTree, x: jax.Array, cfg: ModelConfig):
     """Dropless expert layer of one chip of an expert-parallel deployment.
 
@@ -484,39 +581,39 @@ def apply_moe_held(params: PyTree, x: jax.Array, cfg: ModelConfig):
     tokens. The shared experts run for every token. What the absent experts
     would add is left out; with every expert held this is the whole layer.
 
-    Returns (out, load-balancing loss, (B, S) int32 count of each token's
-    pairs routed to held experts)."""
+    The pair buffer runs the smallest rung of :func:`pair_ladder` that holds
+    the call's held pairs, chosen on the device (a conditional); the sort
+    puts those pairs first, so every rung computes the same rows.
+
+    Returns (out, load-balancing loss, counts): ``pairs``, each token's
+    (B, S) int32 count of pairs routed to held experts, and the call's
+    buffer (:func:`_routing`)."""
     moe = cfg.moe
     B, S, D = x.shape
     E, K, Eh, first = moe.num_experts, moe.top_k, moe.num_held, moe.held_first
     xt = x.reshape(B * S, D)
-    T = xt.shape[0]
+    ladder = pair_ladder(B * S, moe)
     with jax.named_scope("moe"):
         probs, topw, topi = _gate(params, xt, moe)
         local = topi - first
         held = (local >= 0) & (local < Eh)
         key = jnp.where(held, local, Eh).reshape(-1)  # not held: sorts last
-        # every held pair fits: a token's K experts are distinct
-        n = T * min(K, Eh)
-        order = jnp.argsort(key, stable=True)[:n]
-        tok = order // K
-        live = key[order] < Eh
-        w = jnp.where(live, topw.reshape(-1)[order], 0.0)
+        # every held pair fits the top rung: a token's K experts are distinct
+        order = jnp.argsort(key, stable=True)[:ladder[-1]]
         sizes = jnp.sum(jax.nn.one_hot(key, Eh, dtype=jnp.int32), axis=0)
-        # rows past the groups (pairs not held) are left undefined by a
-        # grouped product on some backends: select them away before and
-        # after each product, so that neither they nor their gradients
-        # reach a token
-        xs = jnp.where(live[:, None], xt[tok], 0.0)
-        h = jax.nn.silu(_grouped(xs, params["wg"], sizes)) * _grouped(xs, params["wu"], sizes)
-        h = jnp.where(live[:, None], h, 0.0)
-        y = _grouped(h, params["wd"], sizes)
-        y = jnp.where(live[:, None], y * w[:, None].astype(y.dtype), 0.0)
-        out = jnp.zeros((T, D), y.dtype).at[tok].add(y).reshape(B, S, D)
+        experts = {k: params[k] for k in ("wg", "wu", "wd")}
+        args = (experts, xt, topw.reshape(-1), order, key, sizes)
+        if len(ladder) == 1:
+            rung = jnp.zeros((), jnp.int32)
+            out = _held_pairs(ladder[0], K, *args)
+        else:
+            rung = _rung(ladder, sizes)
+            out = _laddered(ladder, K, *args)
+        out = out.reshape(B, S, D)
         if moe.num_shared:
             out = out + apply_dense_ffn(params["shared"], x)
-    routed = jnp.sum(held, axis=-1, dtype=jnp.int32).reshape(B, S)
-    return out, _balance_loss(probs, topi, E, K), routed
+    pairs = jnp.sum(held, axis=-1, dtype=jnp.int32).reshape(B, S)
+    return out, _balance_loss(probs, topi, E, K), _routing(pairs, rung, ladder)
 
 
 def apply_moe_ffn(
@@ -525,7 +622,7 @@ def apply_moe_ffn(
     cfg: ModelConfig,
     capacity_factor: float = 1.25,
     group_size: int = 4096,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, dict]:
     """GShard-style top-k dispatch MoE (expert-parallel friendly), or the
     dropless :func:`apply_moe_held` under ``cfg.moe_dropless``.
 
@@ -533,8 +630,10 @@ def apply_moe_ffn(
     top-k experts subject to per-expert capacity C = ceil(k*G*cf/E); overflow
     tokens fall through (residual connection carries them). The last group
     is padded with zero tokens, which queue behind every real token and so
-    never take a real token's slot. Returns (out, aux_loss, routed): the
-    standard load-balancing loss and each token's count of dispatched pairs.
+    never take a real token's slot. Returns (out, aux_loss, counts): the
+    standard load-balancing loss, and each token's count of dispatched pairs
+    and the call's one buffer of ``E * C`` rows per group
+    (:func:`_routing`).
     """
     if cfg.moe_dropless:
         return apply_moe_held(params, x, cfg)
@@ -579,7 +678,7 @@ def apply_moe_ffn(
     y = out.reshape(n_groups * g, D)[:T].reshape(B, S, D)
     if moe.num_shared:
         y = y + apply_dense_ffn(params["shared"], x)
-    return y, aux, routed
+    return y, aux, _routing(routed, jnp.zeros((), jnp.int32), (n_groups * E * C,))
 
 
 # ---------------------------------------------------------------------------

@@ -176,7 +176,7 @@ def _apply_layer(
     # full-d_ff partial sums + all-reduce (§Perf iteration 2)
     x = dist.constrain(x + mix, "batch", None, None)
     aux = jnp.zeros((), jnp.float32)
-    routed = jnp.zeros(x.shape[:2], jnp.int32)
+    routed = L.no_routing(x.shape[:2])
     if spec.ffn != "none":
         h2 = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
         if spec.ffn == "dense":
@@ -271,8 +271,10 @@ def forward(
     routed: bool = False,
 ):
     """Returns (logits, moe_aux_loss, new_cache), and with ``routed`` also
-    the (B, S) count of each position's (token, expert) pairs the expert
-    layers computed.
+    the expert layers' counts summed over them (``layers.apply_moe_ffn``'s):
+    the (B, S) count of each position's (token, expert) pairs they computed
+    (``pairs``), and their calls, the calls that ran the smallest pair
+    buffer and the buffers' rows.
 
     batch: {"tokens": (B,S) int32} or {"embeds": (B,S,D)} for frontend-stub
     archs. Decode mode iff ``cache`` is not None (then S == 1 and the new
@@ -300,7 +302,7 @@ def forward(
         x = x + _sinusoidal(x.shape[1], cfg.d_model, x.dtype)[None]
 
     aux_total = jnp.zeros((), jnp.float32)
-    routed_total = jnp.zeros(x.shape[:2], jnp.int32)
+    routed_total = L.no_routing(x.shape[:2])
     new_cache: dict[str, Any] = {"len": pos0 + x.shape[1]} if collect else None
 
     prefix_ad = adapters.get("prefix") or [None] * len(cfg.prefix)
@@ -311,7 +313,7 @@ def forward(
             adapter=prefix_ad[i], lora_scale=lora_scale,
         )
         aux_total += aux
-        routed_total += r
+        routed_total = jax.tree_util.tree_map(jnp.add, routed_total, r)
         if collect:
             new_cache.setdefault("prefix", []).append(nc)
 
@@ -329,7 +331,7 @@ def forward(
                     adapter=la.get(f"slot{i}"), lora_scale=lora_scale,
                 )
                 aux_c += aux
-                routed_c += r
+                routed_c = jax.tree_util.tree_map(jnp.add, routed_c, r)
                 ncs[f"slot{i}"] = nc if nc is not None else 0
             return (x_c, aux_c, routed_c), (ncs if collect else 0)
 

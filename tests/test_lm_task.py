@@ -5,6 +5,8 @@ head-only freezing), delta-only payload billing through ``model_bytes``
 (the FrozenBase wrapper contributes zero bytes), loop/fleet backend
 agreement, and end-to-end runs through both ``run_sync`` (FedAvg) and the
 coalesced async event loop (EchoPFL)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -343,6 +345,51 @@ def test_train_launch_records_tokens_and_routed_pairs(tmp_path):
                     stats[e.name] = dict(e.stats)
     tokens = 2 * 8 * (1 + 2 + 3)  # sequences x length x each client's epochs
     assert int(stats["echopfl/train/launch"]["tokens"]) == tokens
-    pairs = int(stats["echopfl/train/routed"]["pairs"])
+    routed = {k: int(v) for k, v in stats["echopfl/train/routed"].items()}
+    pairs = routed["pairs"]
     held, k = cfg.moe.num_held, cfg.moe.top_k
     assert 0 < pairs <= tokens * cfg.num_periods * min(k, held)
+    # the expert layers' calls in the 3 epochs (each has an active client),
+    # those that ran the smallest pair buffer and the buffers' rows, each
+    # at most the whole buffer of the launch's 4 padded clients' tokens
+    assert routed["calls"] == 3 * sum(spec.ffn == "moe" for spec in cfg.all_layers)
+    assert 0 <= routed["small"] <= routed["calls"]
+    assert pairs <= routed["rows"] <= routed["calls"] * 4 * 2 * 8 * min(k, held)
+
+
+def test_expert_layer_stays_a_conditional_in_a_training_step():
+    """The pair buffer's rung is a real branch inside the clients' training
+    step: the jaxpr of ``LMTask._scan_train`` holds the expert layer's
+    conditional over its three rungs (forward, and the backward pass that
+    recomputes its rung), not a select over every rung's result, which a
+    ``vmap`` over the clients would make of it."""
+    import dataclasses
+
+    import jax.extend
+    from repro.fl.lm_task import LMTask
+    from repro.models import layers as L
+
+    cfg = _mla_moe_cfg()
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, num_experts=64, top_k=6, held=8))
+    task = LMTask.build(cfg, 0, lora_rank=4, targets=("wq", "w_dkv"))
+    C, n, S = 2, 2, 64
+    assert len(L.pair_ladder(C * n * S, cfg.moe)) == 3
+    delta = jax.vmap(task.init_params)(jax.random.split(jax.random.PRNGKey(0), C))
+    tokens = jnp.zeros((C, n, S), jnp.int32)
+    step = functools.partial(task._scan_train, max_epochs=2)
+    jaxpr = jax.make_jaxpr(step)(task.operands, delta, tokens, tokens, jnp.ones((C, n)), jnp.ones((C,)),
+                                 jnp.full((C,), 2, jnp.int32), jnp.zeros((C,)))
+
+    def eqns(j):
+        for e in j.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        yield from eqns(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        yield from eqns(sub)
+
+    found = list(eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "cond" and len(e.params["branches"]) == 3 for e in found) >= 2
+    assert not [e for e in found if e.primitive.name == "select_n" and len(e.invars) > 3]
